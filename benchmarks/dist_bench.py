@@ -19,7 +19,10 @@ statistic a deployment would tune it from.
 
 The sweep runs in a subprocess so the forced host-device count never leaks
 into (or collides with) the parent's already-initialized jax runtime — the
-same isolation trick tests/test_distributed.py uses.
+same isolation trick tests/test_distributed.py uses.  The child is pinned to
+``JAX_PLATFORMS=cpu`` (its N devices are forced host devices), so it can
+never contend with the parent for an accelerator, and its payload is
+labelled ``"platform": "cpu"``: these are CPU timings, not chip numbers.
 
     PYTHONPATH=src python -m benchmarks.dist_bench \
         --out experiments/dist_bench.json
@@ -131,7 +134,8 @@ def _child(args) -> Dict:
               f"scan={rec['scan_ms']:.1f}ms compact={rec['compact_ms']:.1f}ms "
               f"({rec['speedup']:.2f}x)", file=sys.stderr)
 
-    return {"n": args.n, "m": args.m, "L": L_valid, "n_shards": D,
+    return {"platform": jax.devices()[0].platform,
+            "n": args.n, "m": args.m, "L": L_valid, "n_shards": D,
             "leaf_capacity": args.leaf_capacity, "n_queries": args.queries,
             "levels": levels}
 
@@ -143,6 +147,7 @@ def bench_dist(n: int = 48_000, m: int = 128, leaf_capacity: int = 128,
     from . import common
 
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"       # forced host devices, never the chip
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}"
                         ).strip()
@@ -158,7 +163,7 @@ def bench_dist(n: int = 48_000, m: int = 128, leaf_capacity: int = 128,
             f"dist_bench child failed:\n{r.stdout[-2000:]}\n{r.stderr[-4000:]}")
     payload = json.loads(r.stdout)
     rows = [common.csv_line(
-        f"dist/{rec['level']}", rec["compact_ms"] * 1e3,
+        f"dist/{payload['platform']}/{rec['level']}", rec["compact_ms"] * 1e3,
         f"prune={rec['pruning_ratio']:.3f};scan={rec['scan_ms']:.1f}ms;"
         f"compact={rec['compact_ms']:.1f}ms;cap={rec['max_survivors']};"
         f"speedup={rec['speedup']:.2f}x")

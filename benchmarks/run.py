@@ -55,6 +55,8 @@ def main() -> None:
                     help="run one registered suite and exit")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.suite:
         _run_suite(args.suite, args.out)

@@ -29,12 +29,12 @@ lowers on the production mesh.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import conformal, engine
@@ -75,45 +75,51 @@ class ShardedLeaFi:
 
     def query_coords(self, queries: jnp.ndarray) -> jnp.ndarray:
         """Map raw queries to pre-scaled box coordinates (see kernels.box_lb)."""
-        from . import summaries
-        if self.kind == "dstree":
-            s = self.lb_lo.shape[-1] // 2
-            st = summaries.segment_stats(queries, s)
-            q = jnp.concatenate([st[..., 0], st[..., 1]], -1)
-        else:
-            wl = self.lb_lo.shape[-1]
-            q = summaries.paa(queries, wl)
-        return q * jnp.asarray(self.qscale)
+        return _query_coords(self.kind, self.lb_lo, self.qscale, queries)
+
+
+def _query_coords(kind: str, lb_lo, qscale, queries: jnp.ndarray):
+    """Raw queries → the pre-scaled box coordinates of ``lb_lo``'s layout."""
+    from . import summaries
+    if kind == "dstree":
+        st = summaries.segment_stats(queries, lb_lo.shape[-1] // 2)
+        q = jnp.concatenate([st[..., 0], st[..., 1]], -1)
+    else:
+        q = summaries.paa(queries, lb_lo.shape[-1])
+    return q * jnp.asarray(qscale)
 
 
 def make_search_mesh(n_data: int, n_model: int,
                      data_axis: str = "data", model_axis: str = "model"):
-    """A (data, model) mesh for the distributed search, across jax versions.
+    """A (data, model) mesh for the distributed search (one shared
+    constructor for tests, benchmarks and serving)."""
+    return jax.make_mesh((n_data, n_model), (data_axis, model_axis),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
-    jax >= 0.5 wants explicit axis types on ``make_mesh``; older versions
-    don't have ``AxisType``.  One shared guard instead of three copies
-    (tests, benchmarks, serving).
+
+def shard_leafi(lfi: LeaFiIndex, n_shards: Optional[int] = None,
+                quality_target: Optional[float] = 0.99, *,
+                mesh: Optional[Mesh] = None,
+                model_axis: str = "model") -> ShardedLeaFi:
+    """Partition a built LeaFiIndex into n_shards leaf groups.
+
+    With a ``mesh``, shard ``s`` of every per-shard array is placed on the
+    devices at position ``s`` of its ``model_axis``
+    (``NamedSharding(mesh, P(model_axis))``): each device holds only its own
+    leaves, and ``n_shards`` defaults to that axis' size.  Without one the
+    arrays stay uncommitted on the default device (single-device use).
     """
-    shape, names = (n_data, n_model), (data_axis, model_axis)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    return jax.make_mesh(shape, names)
-
-
-def shard_leafi(lfi: LeaFiIndex, n_shards: int,
-                quality_target: Optional[float] = 0.99) -> ShardedLeaFi:
-    """Partition a built LeaFiIndex into n_shards leaf groups."""
-    from . import summaries
+    from ..kernels.filter_mlp import ref as mlp_ref
+    if mesh is not None:
+        n_shards = n_shards or int(mesh.shape[model_axis])
     index = lfi.index
     L = index.n_leaves
     sizes = np.asarray(index.leaf_size)
     order = np.argsort(-sizes, kind="stable")
     # round-robin by size → balanced rows per shard
     shard_of = np.empty(L, np.int64)
-    for rank, leaf in enumerate(order):
-        shard_of[leaf] = rank % n_shards
-    P_max = max((shard_of == s).sum() for s in range(n_shards))
+    shard_of[order] = np.arange(L) % n_shards
+    P_max = int(np.bincount(shard_of, minlength=n_shards).max())
 
     # pre-scaled box edges (shared form for both backbones; cf. kernels.box_lb)
     if index.kind == "dstree":
@@ -130,20 +136,28 @@ def shard_leafi(lfi: LeaFiIndex, n_shards: int,
         qscale = np.full(wl, scale, np.float32)
 
     m = index.length
-    h = lfi.filter_params["w1"].shape[-1] if lfi.filter_params else m
-    F_of_leaf = {int(lf): i for i, lf in enumerate(lfi.leaf_ids)}
+    params = lfi.filter_params
+    h = params["w1"].shape[-1] if params else m
     offsets_global = conformal.scatter_offsets(
         lfi.tuner, lfi.leaf_ids, L, quality_target) \
         if lfi.tuner is not None else np.zeros(L, np.float32)
+    # filter index of every leaf (−1: no filter); weights dequantized once
+    filter_of = np.full(L, -1, np.int64)
+    filter_of[np.asarray(lfi.leaf_ids, np.int64)] = np.arange(
+        len(lfi.leaf_ids))
+    if params is not None:
+        w1_np, w2_np = (np.asarray(w) for w in mlp_ref.dequantize_weights(
+            params["w1"], params["w2"], params.get("w1_scale"),
+            params.get("w2_scale")))
+        p_np = {k: np.asarray(params[k])
+                for k in ("b1", "b2", "y_mean", "y_std")}
 
     series_np = np.asarray(index.series)
     starts_np = np.asarray(index.leaf_start)
-    rows_max = 0
-    per_shard_rows = []
-    for s in range(n_shards):
-        leaves = np.where(shard_of == s)[0]
-        per_shard_rows.append(int(sizes[leaves].sum()))
-    rows_max = max(per_shard_rows) + index.max_leaf_size  # slack for slicing
+    shard_leaves = [np.where(shard_of == s)[0] for s in range(n_shards)]
+    # slack rows keep every dynamic_slice(start, max_leaf) in bounds
+    rows_max = max(int(sizes[lv].sum()) for lv in shard_leaves) \
+        + index.max_leaf_size
 
     S = n_shards
     out = ShardedLeaFi(
@@ -164,34 +178,37 @@ def shard_leafi(lfi: LeaFiIndex, n_shards: int,
         qscale=qscale.astype(np.float32),
         leaf_global=np.full((S, P_max), L, np.int32),
     )
-    for s in range(n_shards):
-        leaves = np.where(shard_of == s)[0]
-        cursor = 0
-        for j, lf in enumerate(leaves):
-            out.leaf_global[s, j] = int(lf)
-            sz = int(sizes[lf])
-            st = int(starts_np[lf])
-            out.series[s, cursor:cursor + sz] = series_np[st:st + sz]
-            out.leaf_start[s, j] = cursor
-            out.leaf_size[s, j] = sz
-            out.lb_lo[s, j] = lo[lf]
-            out.lb_hi[s, j] = hi[lf]
-            if lfi.filter_params is not None and int(lf) in F_of_leaf:
-                fi = F_of_leaf[int(lf)]
-                out.w1[s, j] = np.asarray(lfi.filter_params["w1"][fi])
-                out.b1[s, j] = np.asarray(lfi.filter_params["b1"][fi])
-                out.w2[s, j] = np.asarray(lfi.filter_params["w2"][fi])
-                out.b2[s, j] = float(lfi.filter_params["b2"][fi])
-                out.y_mean[s, j] = float(lfi.filter_params["y_mean"][fi])
-                out.y_std[s, j] = float(lfi.filter_params["y_std"][fi])
-                out.offsets[s, j] = offsets_global[lf]
-                out.has_filter[s, j] = True
-            cursor += sz
-    # jnp-ify
+    for s, leaves in enumerate(shard_leaves):
+        n_l = len(leaves)
+        sz = sizes[leaves].astype(np.int64)
+        cursor = np.concatenate([[0], np.cumsum(sz)[:-1]])
+        rows = np.repeat(starts_np[leaves] - cursor, sz) + np.arange(sz.sum())
+        out.series[s, :sz.sum()] = series_np[rows]
+        out.leaf_global[s, :n_l] = leaves
+        out.leaf_start[s, :n_l] = cursor
+        out.leaf_size[s, :n_l] = sz
+        out.lb_lo[s, :n_l] = lo[leaves]
+        out.lb_hi[s, :n_l] = hi[leaves]
+        if params is None:
+            continue
+        fi = filter_of[leaves]
+        slot = np.where(fi >= 0)[0]
+        fi = fi[slot]
+        out.w1[s, slot] = w1_np[fi]
+        out.b1[s, slot] = p_np["b1"][fi]
+        out.w2[s, slot] = w2_np[fi]
+        out.b2[s, slot] = p_np["b2"][fi]
+        out.y_mean[s, slot] = p_np["y_mean"][fi]
+        out.y_std[s, slot] = p_np["y_std"][fi]
+        out.offsets[s, slot] = offsets_global[leaves[slot]]
+        out.has_filter[s, slot] = True
+    # one host→device copy per shard, straight onto its own device(s)
+    place = (jnp.asarray if mesh is None else functools.partial(
+        jax.device_put, device=NamedSharding(mesh, P(model_axis))))
     for f in dataclasses.fields(out):
         v = getattr(out, f.name)
         if isinstance(v, np.ndarray) and f.name != "qscale":
-            setattr(out, f.name, jnp.asarray(v))
+            setattr(out, f.name, place(v))
     return out
 
 
@@ -454,12 +471,11 @@ def build_search_fn(mesh: Mesh, max_leaf: int, data_axes=("data",),
                                  max_survivors, dist_impl)
     spec_idx = P(model_axis)
     spec_q = P(data_axes)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         search_fn, mesh=mesh,
         in_specs=(spec_idx,) * 13 + (spec_q, spec_q),
         out_specs=(P(model_axis, *data_axes), P(model_axis, *data_axes)),
-        check_rep=False)
-    from jax.sharding import NamedSharding
+        check_vma=False)
     in_sh = tuple(NamedSharding(mesh, spec_idx) for _ in range(13)) \
         + (NamedSharding(mesh, spec_q), NamedSharding(mesh, spec_q))
     return jax.jit(smapped, in_shardings=in_sh), spec_idx, spec_q
@@ -535,56 +551,10 @@ def make_distributed_search(mesh: Mesh, sharded: ShardedLeaFi,
                 sharded.lb_lo, sharded.lb_hi, sharded.w1, sharded.b1,
                 sharded.w2, sharded.b2, sharded.y_mean, sharded.y_std,
                 sharded.offsets, sharded.has_filter)
+    qscale = jnp.asarray(sharded.qscale)
 
-    if per_query_offsets:
-        if sharded.leaf_global is None:
-            raise ValueError("per_query_offsets needs ShardedLeaFi.leaf_global"
-                             " (re-shard with the current shard_leafi)")
-        idx_pq = idx_args + (sharded.leaf_global,)
-        # qoffsets shard over queries like the batch; the L axis replicates
-        smapped = shard_map(
-            search_fn, mesh=mesh,
-            in_specs=(spec_idx,) * len(idx_pq)
-            + (spec_q, spec_q, P(data_axes, None), spec_q),
-            out_specs=out_specs,
-            check_rep=False,
-        )
-
-        def run_pq(queries, qoffsets, bsf_ub):
-            sh = ShardedLeaFi(*idx_args, max_leaf=max_leaf,
-                              length=sharded.length, kind=sharded.kind,
-                              qscale=sharded.qscale)
-            qcoords = sh.query_coords(queries)
-            out = smapped(*idx_pq, queries, qcoords, qoffsets, bsf_ub)
-            rets = (out[0][0], out[1][0])
-            rest = list(out[2:])
-            if trace:
-                rets = rets + (jax.tree.map(lambda x: x[0], rest.pop(0)),)
-            if audit:
-                rets = rets + (rest.pop(0),)    # (S, P) layout — no unwrap
-            return rets
-
-        donate_kw = {}
-        if donate and jax.default_backend() != "cpu":
-            donate_kw["donate_argnums"] = (0, 1, 2)
-        run = jax.jit(run_pq, **donate_kw)
-        return run, idx_pq, spec_idx, spec_q
-
-    smapped = shard_map(
-        search_fn, mesh=mesh,
-        in_specs=(spec_idx,) * len(idx_args) + (spec_q, spec_q),
-        out_specs=out_specs,
-        check_rep=False,
-    )
-
-    @jax.jit
-    def run(queries):
-        sh = ShardedLeaFi(*idx_args, max_leaf=max_leaf,
-                          length=sharded.length, kind=sharded.kind,
-                          qscale=sharded.qscale)
-        qcoords = sh.query_coords(queries)
-        out = smapped(*idx_args, queries, qcoords)
-        # collectives replicate both outputs across the model axis; row 0 is
+    def unwrap(out):
+        # collectives replicate nn/searched across the model axis; row 0 is
         # the global nn and the all-shard total searched count per query
         rets = (out[0][0], out[1][0])
         rest = list(out[2:])
@@ -594,4 +564,44 @@ def make_distributed_search(mesh: Mesh, sharded: ShardedLeaFi,
             rets = rets + (rest.pop(0),)        # (S, P) layout — no unwrap
         return rets
 
-    return run, idx_args, spec_idx, spec_q
+    # the index arrays are arguments of the jitted programs, never
+    # closed-over constants: a constant would be baked into the HLO, which
+    # at collection scale is gigabytes of program text.
+    if per_query_offsets:
+        if sharded.leaf_global is None:
+            raise ValueError("per_query_offsets needs ShardedLeaFi.leaf_global"
+                             " (re-shard with the current shard_leafi)")
+        idx_pq = idx_args + (sharded.leaf_global,)
+        # qoffsets shard over queries like the batch; the L axis replicates
+        smapped = jax.shard_map(
+            search_fn, mesh=mesh,
+            in_specs=(spec_idx,) * len(idx_pq)
+            + (spec_q, spec_q, P(data_axes, None), spec_q),
+            out_specs=out_specs,
+            check_vma=False,
+        )
+
+        def run_pq(idx, queries, qoffsets, bsf_ub):
+            qcoords = _query_coords(sharded.kind, idx[3], qscale, queries)
+            return unwrap(smapped(*idx, queries, qcoords, qoffsets, bsf_ub))
+
+        donate_kw = {}
+        if donate and jax.default_backend() != "cpu":
+            donate_kw["donate_argnums"] = (1, 2, 3)
+        jitted_pq = jax.jit(run_pq, **donate_kw)
+        return (functools.partial(jitted_pq, idx_pq), idx_pq, spec_idx,
+                spec_q)
+
+    smapped = jax.shard_map(
+        search_fn, mesh=mesh,
+        in_specs=(spec_idx,) * len(idx_args) + (spec_q, spec_q),
+        out_specs=out_specs,
+        check_vma=False,
+    )
+
+    @jax.jit
+    def run(idx, queries):
+        qcoords = _query_coords(sharded.kind, idx[3], qscale, queries)
+        return unwrap(smapped(*idx, queries, qcoords))
+
+    return functools.partial(run, idx_args), idx_args, spec_idx, spec_q

@@ -40,7 +40,7 @@ from ..obs import span
 # ---------------------------------------------------------------------------
 
 
-def make_noisy_queries(series: np.ndarray, n_queries: int, key: jax.Array,
+def make_noisy_queries(series: jax.Array, n_queries: int, key: jax.Array,
                        noise_low: float = 0.1, noise_high: float = 0.4
                        ) -> np.ndarray:
     """Sample series uniformly, add N(0, noise²) with noise ~ U[low, high]."""
@@ -93,7 +93,8 @@ def make_local_queries(index: FlatIndex, leaf_ids: np.ndarray, n_per_leaf: int,
         sizes, keys, n_per_leaf, index.length,
         jnp.float32(noise_low), jnp.float32(noise_high))
     rows = np.asarray(rows) + np.asarray(index.leaf_start)[leaf_ids][:, None]
-    noisy = np.asarray(index.series)[rows] \
+    # gather on the device, add on the host (the reference's rounding)
+    noisy = np.asarray(index.series[jnp.asarray(rows)]) \
         + np.asarray(lvl) * np.asarray(noise)
     return summaries.znormalize(noisy)
 
@@ -115,8 +116,7 @@ def nodewise_nn_distances(index: FlatIndex, queries: jnp.ndarray,
     """
     queries = jnp.atleast_2d(jnp.asarray(queries))
     return engine.nn_distance_all_leaves(
-        jnp.asarray(index.series), jnp.asarray(index.leaf_start),
-        jnp.asarray(index.leaf_size), queries,
+        index.series, index.leaf_start, index.leaf_size, queries,
         max_leaf=index.max_leaf_size, dist_impl=dist_impl)
 
 
@@ -130,8 +130,8 @@ def local_nn_distances(index: FlatIndex, local_queries: np.ndarray,
     ``dynamic_slice`` loop.
     """
     return np.asarray(engine.nn_distance_own_leaf(
-        jnp.asarray(index.series), jnp.asarray(index.leaf_start),
-        jnp.asarray(index.leaf_size), jnp.asarray(local_queries),
+        index.series, index.leaf_start, index.leaf_size,
+        jnp.asarray(local_queries),
         np.asarray(leaf_ids), max_leaf=index.max_leaf_size,
         dist_impl=dist_impl))
 
@@ -218,7 +218,7 @@ def collect_training_data(index: FlatIndex, leaf_ids: np.ndarray,
     """Alg. 1 steps 2–3 on the engine's leaf-slab layer (batched passes)."""
     kg, kl = jax.random.split(key)
     with span("collect.global", cat="build", n_global=n_global):
-        gq = make_noisy_queries(np.asarray(index.series[: index.n_series]),
+        gq = make_noisy_queries(index.series[: index.n_series],
                                 n_global, kg, noise_low, noise_high)
         d_L = np.asarray(nodewise_nn_distances(index, jnp.asarray(gq),
                                                dist_impl))

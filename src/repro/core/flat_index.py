@@ -3,6 +3,12 @@
 The tree builders emit this structure; everything downstream (lower bounds,
 filter training, conformal calibration, search, distribution) consumes it.
 It is a pytree, so it jits, shards and checkpoints like any other JAX state.
+
+The arrays the search reads — ``series``, the leaf layout and the
+summarization payload — live on the default device once ``tree.build_*``
+(or ``serving.session.load_index``) hand the index over (:meth:`on_device`),
+so a query batch copies only its queries.  ``order`` stays a host array: it
+only maps result rows back to original ids, which happens on the host.
 """
 from __future__ import annotations
 
@@ -17,14 +23,14 @@ import numpy as np
 @dataclasses.dataclass
 class FlatIndex:
     kind: str                      # "dstree" | "isax"
-    series: np.ndarray             # (n + max_leaf, m) leaf-sorted, padded
-    order: np.ndarray              # (n,) original id of sorted row i
-    leaf_start: np.ndarray         # (L,)
-    leaf_size: np.ndarray          # (L,)
+    series: jax.Array              # (n + max_leaf, m) leaf-sorted, padded
+    order: np.ndarray              # (n,) original id of sorted row i (host)
+    leaf_start: jax.Array          # (L,)
+    leaf_size: jax.Array           # (L,)
     max_leaf_size: int
     n_series: int
     length: int
-    payload: Dict[str, np.ndarray]  # summarization arrays per kind
+    payload: Dict[str, jax.Array]  # summarization arrays per kind
 
     @property
     def n_leaves(self) -> int:
@@ -45,6 +51,16 @@ class FlatIndex:
                    leaf_start=leaf_start, leaf_size=leaf_size,
                    max_leaf_size=max_leaf_size, n_series=n_series,
                    length=length, payload=payload)
+
+    def on_device(self) -> "FlatIndex":
+        """The same index with its search-side arrays on the default device
+        (one host→device copy; arrays already there are not copied)."""
+        return dataclasses.replace(
+            self, series=jax.device_put(self.series),
+            leaf_start=jax.device_put(self.leaf_start),
+            leaf_size=jax.device_put(self.leaf_size),
+            payload={k: jax.device_put(v) for k, v in self.payload.items()},
+            order=np.asarray(self.order))
 
     # -- convenience --------------------------------------------------------
     def leaf_members(self, leaf: int) -> np.ndarray:

@@ -225,8 +225,7 @@ def search_batched_async(
         d_F = jnp.full(d_lb.shape, -_INF)
 
     res = engine.run_cascade(
-        jnp.asarray(index.series), jnp.asarray(index.leaf_start),
-        jnp.asarray(index.leaf_size), queries, d_lb, d_F,
+        index.series, index.leaf_start, index.leaf_size, queries, d_lb, d_F,
         k=k, max_leaf=index.max_leaf_size, strategy=strategy,
         dist_impl=dist_impl, bsf_ub=bsf_ub, trace=trace, audit=audit)
     return PendingSearch(raw=res, order=np.asarray(index.order),
@@ -392,8 +391,8 @@ def search_early(
         d_F = jnp.full(d_lb.shape, -_INF)
     order = jnp.argsort(d_lb)
     td, ti, n_s, n_plb, n_pf = _search_early_core(
-        jnp.asarray(index.series), jnp.asarray(index.leaf_start),
-        jnp.asarray(index.leaf_size), q[0], d_lb, d_F, order,
+        index.series, index.leaf_start, index.leaf_size, q[0], d_lb, d_F,
+        order,
         k=k, max_leaf=index.max_leaf_size)
     ids_sorted = np.asarray(ti)
     valid = ids_sorted >= 0

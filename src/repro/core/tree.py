@@ -230,4 +230,4 @@ def _flatten(series: np.ndarray, leaves: List[_Node], kind: str,
         n_series=n,
         length=m,
         payload={k: np.asarray(v) for k, v in payload.items()},
-    )
+    ).on_device()

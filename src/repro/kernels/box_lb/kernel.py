@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import tpu_params
+
 
 def _box_kernel(q_ref, lo_ref, hi_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)              # (bq, d)
@@ -50,8 +52,6 @@ def box_lb_kernel(
         ],
         out_specs=pl.BlockSpec((bq, bl), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Q, L), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=tpu_params("parallel", "parallel"),
         interpret=interpret,
     )(q, lo, hi)

@@ -17,6 +17,15 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
+
+
+def tpu_params(*semantics: str) -> pltpu.CompilerParams:
+    """Mosaic compiler parameters: one dimension semantic per grid axis.
+
+    Interpret mode ignores them, so every kernel passes them unconditionally.
+    """
+    return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
 def use_interpret() -> bool:

@@ -32,12 +32,17 @@ cut) and the scales are folded in after the matmul — algebraically exact
 w.r.t. dequantize-then-multiply because each scale is constant per output
 column.
 
-VMEM per fused step at m = h = 128, bf = 8, bq = 128: w1 block 512 KiB f32
-(128 KiB int8) + query tile 64 KiB + hidden 512 KiB — comfortably
-double-buffered.  int8 caveat: the (1, bf·h) layer-2 blocks have a
-single-sublane layout that real-MXU Mosaic may reject (min int8 tile is
-(32, 128)); the path is interpret-validated here and flagged for on-device
-tuning in the ROADMAP's hardware-gated measurement item.
+Block layout: Mosaic takes a block whose last two dimensions are multiples
+of the (8, 128) tile or equal the array's own.  Every per-filter vector
+therefore carries a unit middle axis — (F, 1, h) rows, (G, 1, bf·h) grouped
+rows, (G, bf, 1) per-filter columns — whose (1, ·) / (·, 1) block equals
+the full dimension; the outputs are written filter-major, so no in-kernel
+transpose is needed.
+
+VMEM per fused step at m = h = 256, bf = 8, bq = 128: w1 block 2 MiB f32
+(512 KiB int8), double-buffered; query tile 128 KiB; hidden and its
+layer-2 product 1 MiB each; the (bf, bf·h) group-sum operand 64 KiB.
+tests/test_tpu_compile.py compiles all three weight dtypes at those widths.
 """
 from __future__ import annotations
 
@@ -47,32 +52,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import tpu_params
+
+_NT = (((1,), (1,)), ((), ()))        # contract both operands' last axis
+_NN = (((1,), (0,)), ((), ()))
+
 
 def _mlp_kernel(q_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)                       # (bq, m)
     w1 = w1_ref[0].astype(jnp.float32)                       # (m, h)
     hidden = jnp.maximum(
-        jax.lax.dot_general(q, w1, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        + b1_ref[...].astype(jnp.float32),                   # (bq, h)
+        jax.lax.dot_general(q, w1, _NN, preferred_element_type=jnp.float32)
+        + b1_ref[0].astype(jnp.float32),                     # (bq, h)
         0.0,
     )
-    w2 = w2_ref[...].astype(jnp.float32)                     # (1, h)
-    out = jax.lax.dot_general(hidden, w2, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)  # (bq, 1)
-    o_ref[...] = out.T + b2_ref[...]                         # (1, bq)
+    w2 = w2_ref[0].astype(jnp.float32)                       # (1, h)
+    out = jax.lax.dot_general(w2, hidden, _NT,
+                              preferred_element_type=jnp.float32)  # (1, bq)
+    o_ref[0] = out + b2_ref[0]                               # (1, bq)
 
 
 def filter_mlp_kernel(
     queries: jnp.ndarray,          # (Q, m), Q multiple of bq
     w1: jnp.ndarray,               # (F, m, h)
-    b1: jnp.ndarray,               # (F, h)
-    w2: jnp.ndarray,               # (F, h)
-    b2: jnp.ndarray,               # (F, 1)
+    b1: jnp.ndarray,               # (F, 1, h)
+    w2: jnp.ndarray,               # (F, 1, h)
+    b2: jnp.ndarray,               # (F, 1, 1)
     *,
     bq: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """Per-filter sweep → (F, 1, Q) raw predictions."""
     Q, m = queries.shape
     F, _, h = w1.shape
     grid = (F, Q // bq)
@@ -82,15 +92,13 @@ def filter_mlp_kernel(
         in_specs=[
             pl.BlockSpec((bq, m), lambda f, q: (q, 0)),
             pl.BlockSpec((1, m, h), lambda f, q: (f, 0, 0)),
-            pl.BlockSpec((1, h), lambda f, q: (f, 0)),
-            pl.BlockSpec((1, h), lambda f, q: (f, 0)),
-            pl.BlockSpec((1, 1), lambda f, q: (f, 0)),
+            pl.BlockSpec((1, 1, h), lambda f, q: (f, 0, 0)),
+            pl.BlockSpec((1, 1, h), lambda f, q: (f, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda f, q: (f, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq), lambda f, q: (f, q)),
-        out_shape=jax.ShapeDtypeStruct((F, Q), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        out_specs=pl.BlockSpec((1, 1, bq), lambda f, q: (f, 0, q)),
+        out_shape=jax.ShapeDtypeStruct((F, 1, Q), jnp.float32),
+        compiler_params=tpu_params("parallel", "parallel"),
         interpret=interpret,
     )(queries, w1, b1, w2, b2)
 
@@ -100,13 +108,25 @@ def filter_mlp_kernel(
 # ---------------------------------------------------------------------------
 
 
-def _group_sum_operand(bfh: int, bf: int, h: int) -> jnp.ndarray:
-    """(bf·h, bf) block-diagonal 0/1 matrix: column f sums its filter's h
+def _group_sum_operand(bf: int, bfh: int, h: int) -> jnp.ndarray:
+    """(bf, bf·h) block-diagonal 0/1 matrix: row f sums its filter's h
     hidden lanes.  Built from iota so it materializes in-register — no HBM
     operand, and the layer-2 reduction stays a plain MXU matmul."""
-    row = jax.lax.broadcasted_iota(jnp.int32, (bfh, bf), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (bfh, bf), 1)
-    return (row // h == col).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (bf, bfh), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bf, bfh), 1)
+    return (col // h == row).astype(jnp.float32)
+
+
+def _fused_epilogue(hw, b2_ref, ym_ref, ys_ref, off_ref, o_ref, h, bf):
+    # layer-2 group sum straight into filter-major (bf, bq) — the output
+    # block's own layout, so nothing is transposed in-kernel
+    z = jax.lax.dot_general(
+        _group_sum_operand(bf, hw.shape[1], h), hw, _NT,
+        preferred_element_type=jnp.float32) + b2_ref[0]      # (bf, bq)
+    # epilogue: de-standardize + conformal offset, same op order as the
+    # unfused composition (z·y_std + y_mean, then −offset) so the fused
+    # output is bitwise-equal to it.
+    o_ref[...] = z * ys_ref[0] + ym_ref[0] - off_ref[0]
 
 
 def _fused_body(q_ref, w1_ref, b1_ref, w2_ref, b2_ref, ym_ref, ys_ref,
@@ -114,21 +134,12 @@ def _fused_body(q_ref, w1_ref, b1_ref, w2_ref, b2_ref, ym_ref, ys_ref,
     q = q_ref[...].astype(jnp.float32)                       # (bq, m)
     w1 = w1_ref[0].astype(jnp.float32)                       # (m, bf·h)
     hidden = jnp.maximum(
-        jax.lax.dot_general(q, w1, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        + b1_ref[...],                                       # (bq, bf·h)
+        jax.lax.dot_general(q, w1, _NN, preferred_element_type=jnp.float32)
+        + b1_ref[0],                                         # (bq, bf·h)
         0.0,
     )
-    w2 = w2_ref[...].astype(jnp.float32)                     # (1, bf·h)
-    hw = hidden * w2                                         # (bq, bf·h)
-    z = jax.lax.dot_general(
-        hw, _group_sum_operand(hw.shape[1], bf, h),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b2_ref[...]    # (bq, bf)
-    # epilogue: de-standardize + conformal offset, same op order as the
-    # unfused composition (z·y_std + y_mean, then −offset) so the fused
-    # output is bitwise-equal to it.
-    o_ref[...] = (z * ys_ref[...] + ym_ref[...] - off_ref[...]).T
+    hw = hidden * w2_ref[0].astype(jnp.float32)              # (bq, bf·h)
+    _fused_epilogue(hw, b2_ref, ym_ref, ys_ref, off_ref, o_ref, h, bf)
 
 
 def _fused_body_q(q_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref, b2_ref,
@@ -139,33 +150,28 @@ def _fused_body_q(q_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref, b2_ref,
     q = q_ref[...].astype(jnp.float32)                       # (bq, m)
     w1 = w1_ref[0].astype(jnp.float32)                       # (m, bf·h) deq.
     hidden = jnp.maximum(
-        jax.lax.dot_general(q, w1, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-        * s1_ref[...]                                        # (1, bf·h)
-        + b1_ref[...],
+        jax.lax.dot_general(q, w1, _NN, preferred_element_type=jnp.float32)
+        * s1_ref[0]                                          # (1, bf·h)
+        + b1_ref[0],
         0.0,
     )
-    w2 = w2_ref[...].astype(jnp.float32) * s2_ref[...]       # (1, bf·h)
-    hw = hidden * w2
-    z = jax.lax.dot_general(
-        hw, _group_sum_operand(hw.shape[1], bf, h),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) + b2_ref[...]
-    o_ref[...] = (z * ys_ref[...] + ym_ref[...] - off_ref[...]).T
+    w2 = w2_ref[0].astype(jnp.float32) * s2_ref[0]           # (1, bf·h)
+    _fused_epilogue(hidden * w2, b2_ref, ym_ref, ys_ref, off_ref, o_ref, h,
+                    bf)
 
 
 def fused_filter_mlp_kernel(
     queries: jnp.ndarray,          # (Q, m), Q multiple of bq
     w1g: jnp.ndarray,              # (G, m, bf·h) grouped layer-1 blocks
-    b1g: jnp.ndarray,              # (G, bf·h) float32
-    w2g: jnp.ndarray,              # (G, bf·h)
-    b2g: jnp.ndarray,              # (G, bf) float32
-    ymg: jnp.ndarray,              # (G, bf) per-filter y_mean
-    ysg: jnp.ndarray,              # (G, bf) per-filter y_std
-    offg: jnp.ndarray,             # (G, bf) conformal offsets (zeros = none)
+    b1g: jnp.ndarray,              # (G, 1, bf·h) float32
+    w2g: jnp.ndarray,              # (G, 1, bf·h)
+    b2g: jnp.ndarray,              # (G, bf, 1) float32
+    ymg: jnp.ndarray,              # (G, bf, 1) per-filter y_mean
+    ysg: jnp.ndarray,              # (G, bf, 1) per-filter y_std
+    offg: jnp.ndarray,             # (G, bf, 1) conformal offsets (zeros = none)
     *,
-    s1g: jnp.ndarray | None = None,   # (G, bf·h) int8 scales, expanded
-    s2g: jnp.ndarray | None = None,   # (G, bf·h)
+    s1g: jnp.ndarray | None = None,   # (G, 1, bf·h) int8 scales, expanded
+    s2g: jnp.ndarray | None = None,   # (G, 1, bf·h)
     bq: int = 128,
     bf: int = 8,
     interpret: bool = False,
@@ -182,8 +188,8 @@ def fused_filter_mlp_kernel(
     quantized = s1g is not None
     body = functools.partial(
         _fused_body_q if quantized else _fused_body, h=h, bf=bf)
-    vec_spec = pl.BlockSpec((1, bfh), lambda g, t: (g, 0))
-    flt_spec = pl.BlockSpec((1, bf), lambda g, t: (g, 0))
+    vec_spec = pl.BlockSpec((1, 1, bfh), lambda g, t: (g, 0, 0))
+    flt_spec = pl.BlockSpec((1, bf, 1), lambda g, t: (g, 0, 0))
     in_specs = [
         pl.BlockSpec((bq, m), lambda g, t: (t, 0)),
         pl.BlockSpec((1, m, bfh), lambda g, t: (g, 0, 0)),
@@ -205,8 +211,6 @@ def fused_filter_mlp_kernel(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bf, bq), lambda g, t: (g, t)),
         out_shape=jax.ShapeDtypeStruct((G * bf, Q), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel"))
-        ) if not interpret else None,
+        compiler_params=tpu_params("parallel", "parallel"),
         interpret=interpret,
     )(*operands)

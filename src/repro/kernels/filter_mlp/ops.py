@@ -48,9 +48,9 @@ def filter_predict(
     b1p = _pad_to(b1, 128, 1)
     w2p = _pad_to(w2, 128, 1)
     out = kernel.filter_mlp_kernel(
-        qp, w1p, b1p, w2p, b2[:, None], bq=bq, interpret=interpret
-    )
-    return out[:, :Q]
+        qp, w1p, b1p[:, None], w2p[:, None], b2[:, None, None], bq=bq,
+        interpret=interpret)
+    return out[:, 0, :Q]
 
 
 def pack_fused(w1, b1, w2, b2, y_mean, y_std, offsets=None,
@@ -59,33 +59,36 @@ def pack_fused(w1, b1, w2, b2, y_mean, y_std, offsets=None,
 
     Layer-1 weights become (G, m', bf·h') blocks (filter-major within the
     lane axis: lane j of group g is filter ``g·bf + j//h'``), layer-2 rows
-    and per-filter vectors follow the same layout.  int8 scales are expanded
-    to per-lane rows here so the kernel's dequant is a plain broadcast
-    multiply.  Grouping is cheap (one transpose-copy of the weight bytes)
-    but callers on a hot loop should pack once and reuse.
+    follow the same lane layout as (G, 1, bf·h') and per-filter scalars
+    become (G, bf, 1) columns (the kernel module says why the unit axes).
+    int8 scales are expanded to per-lane rows here so the kernel's dequant
+    is a plain broadcast multiply.  Grouping is one transpose-copy of the
+    weight bytes; callers on a hot loop should pack once and reuse.
     """
     F, m, h = w1.shape
     G = -(-F // bf)
     w1p = _pad_to(_pad_to(_pad_to(w1, 128, 1), 128, 2), bf, 0)
     hp = w1p.shape[2]
     w1g = w1p.reshape(G, bf, w1p.shape[1], hp).transpose(0, 2, 1, 3)
+
+    def col(v):                                  # (F,) → (G, bf, 1) f32
+        return _pad_to(v.astype(jnp.float32), bf, 0).reshape(G, bf, 1)
+
     out = {
         "w1g": w1g.reshape(G, w1p.shape[1], bf * hp),
         "b1g": _pad_to(_pad_to(b1, 128, 1), bf, 0)
-        .astype(jnp.float32).reshape(G, bf * hp),
-        "w2g": _pad_to(_pad_to(w2, 128, 1), bf, 0).reshape(G, bf * hp),
-        "b2g": _pad_to(b2, bf, 0).astype(jnp.float32).reshape(G, bf),
-        "ymg": _pad_to(y_mean, bf, 0).astype(jnp.float32).reshape(G, bf),
-        "ysg": _pad_to(y_std, bf, 0).astype(jnp.float32).reshape(G, bf),
-        "offg": (jnp.zeros((G, bf), jnp.float32) if offsets is None else
-                 _pad_to(offsets.astype(jnp.float32), bf, 0).reshape(G, bf)),
+        .astype(jnp.float32).reshape(G, 1, bf * hp),
+        "w2g": _pad_to(_pad_to(w2, 128, 1), bf, 0).reshape(G, 1, bf * hp),
+        "b2g": col(b2), "ymg": col(y_mean), "ysg": col(y_std),
+        "offg": (jnp.zeros((G, bf, 1), jnp.float32) if offsets is None
+                 else col(offsets)),
     }
     for name, s in (("s1g", w1_scale), ("s2g", w2_scale)):
         if s is not None:
             srow = jnp.broadcast_to(
                 _pad_to(s.astype(jnp.float32), bf, 0)[:, None],
                 (G * bf, hp))
-            out[name] = srow.reshape(G, bf * hp)
+            out[name] = srow.reshape(G, 1, bf * hp)
     return out
 
 

@@ -19,6 +19,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..common import tpu_params
+
+# f32 contractions at full precision: the MXU's default rounds f32 operands
+# to bf16, which shifts ‖q‖²+‖s‖²−2·q·sᵀ by far more than f32 rounding.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _l2_kernel(q_ref, s_ref, qn_ref, sn_ref, o_ref, *, nk: int):
     k = pl.program_id(2)
@@ -30,8 +36,8 @@ def _l2_kernel(q_ref, s_ref, qn_ref, sn_ref, o_ref, *, nk: int):
     q = q_ref[...].astype(jnp.float32)
     s = s_ref[...].astype(jnp.float32)
     o_ref[...] += -2.0 * jax.lax.dot_general(
-        q, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        q, s, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -65,9 +71,7 @@ def pairwise_l2_kernel(
         ],
         out_specs=pl.BlockSpec((bq, bb), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Q, B), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=tpu_params("parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(queries, series, q_norms, s_norms)
 
@@ -82,8 +86,8 @@ def _slab_l2_kernel(q_ref, s_ref, qn_ref, sn_ref, o_ref, *, nk: int):
     q = q_ref[0].astype(jnp.float32)
     s = s_ref[0].astype(jnp.float32)
     o_ref[0] += -2.0 * jax.lax.dot_general(
-        q, s, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+        q, s, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -124,9 +128,7 @@ def slab_l2_kernel(
         ],
         out_specs=pl.BlockSpec((1, bq, bb), lambda f, i, j, k: (f, i, j)),
         out_shape=jax.ShapeDtypeStruct((F, Nq, R), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=tpu_params("parallel", "parallel", "parallel",
+                                   "arbitrary"),
         interpret=interpret,
     )(queries, slabs, q_norms, s_norms)

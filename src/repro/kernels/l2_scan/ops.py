@@ -11,6 +11,10 @@ from ... import sanitize
 from ..common import pad_to as _pad_to, use_interpret as _use_interpret
 
 _INF = jnp.float32(jnp.inf)
+# every f32 contraction of the ‖q‖²+‖s‖²−2·q·sᵀ algebra runs at full
+# precision: the TPU default rounds f32 operands to bf16, which moves a
+# z-normalized distance by far more than the exact-search contract allows.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @functools.partial(jax.jit, static_argnames=("bq", "bb", "bk", "interpret"))
@@ -101,6 +105,7 @@ def gathered_leaf_l2(
         qn = (q * q).sum(-1)
         sn = (s * s).sum(-1)
         dot = jnp.einsum("ncrm,nm->ncr", s, q,
+                         precision=_HIGHEST,
                          preferred_element_type=jnp.float32)
         return jnp.sqrt(jnp.maximum(qn[:, None, None] + sn - 2.0 * dot, 0.0))
     raise ValueError(f"unknown gathered-l2 impl {impl!r}")
@@ -199,6 +204,7 @@ def slab_l2(
         qn = (q * q).sum(-1)                             # (F, Nq)
         sn = (s * s).sum(-1)                             # (F, R)
         dot = jnp.einsum("fqm,frm->fqr", q, s,
+                         precision=_HIGHEST,
                          preferred_element_type=jnp.float32)
         return jnp.sqrt(jnp.maximum(
             qn[:, :, None] + sn[:, None, :] - 2.0 * dot, 0.0))
@@ -245,6 +251,7 @@ def shared_slab_l2(
         qn = (q * q).sum(-1)                             # (Q,)
         sn = (s * s).sum(-1)                             # (C, R)
         dot = jnp.einsum("qm,crm->qcr", q, s,
+                         precision=_HIGHEST,
                          preferred_element_type=jnp.float32)
         return jnp.sqrt(jnp.maximum(
             qn[:, None, None] + sn[None, :, :] - 2.0 * dot, 0.0))

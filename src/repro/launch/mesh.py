@@ -9,17 +9,14 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """axis_types only exists on newer jax; older versions default to Auto."""
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+def _auto(n_axes: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh(model: int = 1):
@@ -27,4 +24,4 @@ def make_host_mesh(model: int = 1):
     n = jax.device_count()
     data = max(n // model, 1)
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=_auto(2))

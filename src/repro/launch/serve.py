@@ -61,11 +61,26 @@ def _print_serve_report(report: dict, label: str = "") -> None:
               f"(n={rec['n']})")
 
 
+def leafi_config(seed: int = 0):
+    """The LeaFi build ``--arch leafi`` (and ``chip_smoke.py``) serves.
+
+    DSTree with the paper's leaf capacity; a size threshold
+    ``t_filter_over_t_series=20`` (th = 40 series), so every leaf of a
+    median-split DSTree carries a filter — the paper's Deep-measured 279
+    would need leaves above 558 series and select none at capacity 256.
+    """
+    from ..core import build, filter_training
+    return build.LeaFiConfig(
+        backbone="dstree", leaf_capacity=256, n_global=200, n_local=60,
+        t_filter_over_t_series=20.0, seed=seed,
+        train=filter_training.TrainConfig(epochs=40))
+
+
 def serve_leafi(args) -> None:
     """Open-loop micro-batched serving over the LeaFi engine."""
     import numpy as np
 
-    from ..core import build, filter_training
+    from ..core import build
     from ..core.summaries import znormalize
     from ..obs import SpanRecorder, export as obs_export, set_recorder
     from ..serving import MicroBatcher, ServingSession, poisson_trace
@@ -97,10 +112,7 @@ def serve_leafi(args) -> None:
         n, m = 20_000, 128
         S = rng.standard_normal((n, m), dtype=np.float32).cumsum(axis=1)
         print(f"building LeaFi index over {n}x{m} series...")
-        lfi = build.build_leafi(S, build.LeaFiConfig(
-            backbone="dstree", leaf_capacity=256, n_global=200, n_local=60,
-            t_filter_over_t_series=20.0,
-            train=filter_training.TrainConfig(epochs=40)))
+        lfi = build.build_leafi(S, leafi_config())
         session = ServingSession(lfi, **session_kw)
         if args.ckpt:
             session.save(args.ckpt)
@@ -110,8 +122,7 @@ def serve_leafi(args) -> None:
     idx = session.lfi.index
     rng = np.random.default_rng(args.seed + 1)
     pool = znormalize(
-        np.asarray(idx.series[:idx.n_series])[
-            rng.integers(0, idx.n_series, 256)]
+        np.asarray(idx.series[rng.integers(0, idx.n_series, 256)])
         + 0.3 * rng.standard_normal((256, idx.length)).astype(np.float32))
 
     n_warm = session.warmup(max_batch=args.batch, ks=(args.k,),
@@ -269,7 +280,7 @@ def serve_leafi_distributed(lfi, q, telemetry=None) -> None:
 
     D = max(len(jax.devices()), 1)
     mesh = distributed.make_search_mesh(1, D)
-    sharded = distributed.shard_leafi(lfi, n_shards=D)
+    sharded = distributed.shard_leafi(lfi, mesh=mesh)
     P = sharded.leaf_size.shape[1]
     tuned = None
     if telemetry is not None and telemetry.survivors:
@@ -354,6 +365,8 @@ def main() -> None:
                          "(batch dispatch/in-flight/harvest lanes + host "
                          "spans; open in Perfetto) (--arch leafi)")
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.arch == "leafi":
         serve_leafi(args)

@@ -34,6 +34,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 from .. import checkpoint
@@ -111,7 +112,8 @@ def load_index(path: str) -> build.LeaFiIndex:
 
     Search over the loaded index is pinned identical to the saved one
     (tests/test_serving.py): the arrays round-trip verbatim and the engine
-    sees the same inputs in the same process context.
+    sees the same inputs in the same process context.  The backbone arrays
+    and filter parameters are placed on the default device here, once.
     """
     flat, meta = checkpoint.load_pytree(path)
 
@@ -128,8 +130,9 @@ def load_index(path: str) -> build.LeaFiIndex:
         leaf_start=group("leaf_start"), leaf_size=group("leaf_size"),
         max_leaf_size=int(meta["max_leaf_size"]),
         n_series=int(meta["n_series"]), length=int(meta["length"]),
-        payload=group("payload"))
-    params = group("filter_params") or None
+        payload=group("payload")).on_device()
+    params = {k: jax.device_put(v)
+              for k, v in group("filter_params").items()} or None
     tn = group("tuner")
     tuner = conformal.AutoTuner(**tn) if tn else None
     cal = group("calib")
@@ -181,7 +184,6 @@ class _PendingDist:
     n_leaves: int
 
     def block_until_ready(self) -> "_PendingDist":
-        import jax
         jax.block_until_ready(self.nn)
         return self
 
@@ -214,7 +216,8 @@ class DistributedExecutor:
         self.lfi = lfi
         self.n_leaves = lfi.index.n_leaves
         n_model = int(mesh.shape[model_axis])
-        self.sharded = distributed.shard_leafi(lfi, n_model)
+        self.sharded = distributed.shard_leafi(lfi, n_model, mesh=mesh,
+                                               model_axis=model_axis)
         self.run, self._idx_args, _, _ = distributed.make_distributed_search(
             mesh, self.sharded, data_axes=data_axes, model_axis=model_axis,
             strategy=strategy, max_survivors=max_survivors,

@@ -68,11 +68,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import sys
 sys.path.insert(0, "src")
 from repro.analysis import hlo_stats
-if hasattr(jax.sharding, "AxisType"):  # jax >= 0.5 wants explicit axis types
-    mesh = jax.make_mesh((8,), ("d",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-else:
-    mesh = jax.make_mesh((8,), ("d",))
+mesh = jax.make_mesh((8,), ("d",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.ShapeDtypeStruct((1024, 512), jnp.float32)
 w = jax.ShapeDtypeStruct((512, 256), jnp.float32)
 f = lambda x, w: x @ w

@@ -50,7 +50,6 @@ import sys
 sys.path.insert(0, "src")
 import dataclasses
 import numpy as np, jax, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.core import build, distributed, engine, filter_training
 from repro.core.summaries import znormalize
@@ -66,7 +65,7 @@ Q = znormalize(S[rng.integers(0, len(S), 16)]
                + 0.3 * rng.standard_normal((16, 64)).astype(np.float32))
 Qj = jnp.asarray(Q)
 
-mesh = distributed.make_search_mesh(2, 2)   # jax-version-guarded make_mesh
+mesh = distributed.make_search_mesh(2, 2)
 sharded = distributed.shard_leafi(lfi, n_shards=2, quality_target=0.99)
 
 def pad_leaves(sh, extra):
@@ -137,10 +136,10 @@ def dual_run(sh, max_survivors=None):
                 jax.lax.pmin(bsf_c, "model")[None],
                 jax.lax.psum(ns_c, "model")[None],
                 ns_s[None], bsf0[None])
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model"),) * 13 + (P(("data",)), P(("data",))),
-        out_specs=(P("model", "data"),) * 6, check_rep=False)
+        out_specs=(P("model", "data"),) * 6, check_vma=False)
     out = jax.jit(smapped)(*idx_args(sh), Qj, sh.query_coords(Qj))
     nn_s, tot_s, nn_c, tot_c, ns_shard, bsf0 = map(np.asarray, out)
     return nn_s[0], tot_s[0], nn_c[0], tot_c[0], ns_shard, bsf0[0]
