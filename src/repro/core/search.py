@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import span
 from . import bounds as bounds_mod
 from . import conformal, engine, filters
 from .flat_index import FlatIndex
@@ -90,23 +91,37 @@ class PendingSearch:
         return self
 
     def result(self) -> SearchResult:
-        """Materialize to a :class:`SearchResult` (blocks on the device)."""
+        """Materialize to a :class:`SearchResult` (blocks on the device).
+
+        Two spans: ``search.wait``, the host's wait until every output it
+        reads is ready, then ``search.fetch``, the device-to-host copies
+        (``n_arrays`` of them, ``bytes`` in all) and the id mapping
+        through ``order``."""
         from ..obs import audit as obs_audit
         from ..obs import trace as obs_trace
         r = self.raw
-        ids_sorted = np.asarray(r.topk_i)
-        valid = ids_sorted >= 0
-        orig = np.where(valid, self.order[
-            np.clip(ids_sorted, 0, self.n_series - 1)], -1)
-        return SearchResult(
-            dists=np.asarray(r.topk_d), ids=orig,
-            searched=np.asarray(r.n_searched),
-            pruned_lb=np.asarray(r.n_pruned_lb),
-            pruned_filter=np.asarray(r.n_pruned_filter),
-            n_leaves=self.n_leaves, computed=np.asarray(r.n_computed),
-            trace=(None if r.trace is None else obs_trace.to_numpy(r.trace)),
-            audit=(None if r.audit is None
-                   else obs_audit.to_numpy(r.audit)))
+        out = [r.topk_i, r.topk_d, r.n_searched, r.n_pruned_lb,
+               r.n_pruned_filter, r.n_computed, r.trace, r.audit]
+        leaves = jax.tree.leaves(out)
+        with span("search.wait", cat="search", q=int(r.topk_d.shape[0]),
+                  k=int(r.topk_d.shape[1])):
+            jax.block_until_ready(leaves)
+        with span("search.fetch", cat="search", n_arrays=len(leaves),
+                  bytes=int(sum(x.nbytes for x in leaves))):
+            ids_sorted = np.asarray(r.topk_i)
+            valid = ids_sorted >= 0
+            orig = np.where(valid, self.order[
+                np.clip(ids_sorted, 0, self.n_series - 1)], -1)
+            return SearchResult(
+                dists=np.asarray(r.topk_d), ids=orig,
+                searched=np.asarray(r.n_searched),
+                pruned_lb=np.asarray(r.n_pruned_lb),
+                pruned_filter=np.asarray(r.n_pruned_filter),
+                n_leaves=self.n_leaves, computed=np.asarray(r.n_computed),
+                trace=(None if r.trace is None
+                       else obs_trace.to_numpy(r.trace)),
+                audit=(None if r.audit is None
+                       else obs_audit.to_numpy(r.audit)))
 
 
 # ---------------------------------------------------------------------------
@@ -200,9 +215,19 @@ def search_batched_async(
     saved, prediction-residual health stats — see ``repro.obs.audit``);
     the materialized ``SearchResult.audit`` is its numpy dict.  Same
     zero-cost-when-off discipline as ``trace``.
+
+    Each layer of the dispatch is a span (:mod:`repro.obs.spans`):
+    ``search.bounds`` (the queries' upload and their lower bounds),
+    ``search.offsets`` (the conformal offsets and their upload),
+    ``search.filters`` (the filter sweep and its scatter onto leaves) and
+    ``search.cascade`` (the engine's enqueue).  An exact search has no
+    offsets or filters span.
     """
-    queries = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
-    d_lb = bounds_mod.lower_bounds(index, queries)                  # (Q, L)
+    shape = np.shape(queries)
+    Q = int(shape[0]) if len(shape) > 1 else 1
+    with span("search.bounds", cat="search", q=Q, n_leaves=index.n_leaves):
+        queries = jnp.atleast_2d(jnp.asarray(queries, jnp.float32))
+        d_lb = bounds_mod.lower_bounds(index, queries)              # (Q, L)
     if quality_target is not None:
         nd = np.ndim(quality_target)
         if nd > 1:
@@ -216,18 +241,24 @@ def search_batched_async(
     offsets = None
     if use_filters and filter_params is not None and tuner is not None \
             and quality_target is not None:
-        offsets = tuner.offsets(quality_target)     # (F,) or (Q, F)
+        with span("search.offsets", cat="search",
+                  per_query=bool(np.ndim(quality_target))):
+            # (F,) or (Q, F)
+            offsets = jnp.asarray(tuner.offsets(quality_target))
     if use_filters and filter_params is not None:
-        d_F = predictions_for_all_leaves(
-            index, filter_params, leaf_ids, queries, offsets, use_kernel,
-            filter_type)
+        with span("search.filters", cat="search", q=Q,
+                  n_filters=len(leaf_ids) if leaf_ids is not None else 0):
+            d_F = predictions_for_all_leaves(
+                index, filter_params, leaf_ids, queries, offsets, use_kernel,
+                filter_type)
     else:
         d_F = jnp.full(d_lb.shape, -_INF)
 
-    res = engine.run_cascade(
-        index.series, index.leaf_start, index.leaf_size, queries, d_lb, d_F,
-        k=k, max_leaf=index.max_leaf_size, strategy=strategy,
-        dist_impl=dist_impl, bsf_ub=bsf_ub, trace=trace, audit=audit)
+    with span("search.cascade", cat="search", q=Q, k=k, strategy=strategy):
+        res = engine.run_cascade(
+            index.series, index.leaf_start, index.leaf_size, queries, d_lb,
+            d_F, k=k, max_leaf=index.max_leaf_size, strategy=strategy,
+            dist_impl=dist_impl, bsf_ub=bsf_ub, trace=trace, audit=audit)
     return PendingSearch(raw=res, order=np.asarray(index.order),
                          n_series=index.n_series, n_leaves=index.n_leaves)
 

@@ -1,27 +1,39 @@
-"""Host-side span profiling: nested context-manager timers with
+"""Host-side span profiling: nested timers with
 ``jax.profiler.TraceAnnotation`` pass-through.
 
 Spans answer "where did the wall-clock go" for the host-orchestrated
 phases the device profiler cannot see — build-pipeline stages
-(collect/train/calibrate), serving dispatch/harvest, checkpoint IO.  Each
-``span(...)`` block records name, category, nesting depth, thread lane and
-wall-clock ``(t0, dur)``; :mod:`repro.obs.export` renders the recorded
-list as Chrome trace-event JSON for Perfetto.
+(collect/train/calibrate), serving dispatch/harvest and the search path
+inside them, checkpoint IO.  Each ``span(...)`` block records name,
+category, nesting depth, thread lane, wall-clock ``(t0, dur)``, its own
+``sid`` (a per-recorder counter) and the ``parent`` sid of the span that
+encloses it on the same thread (−1 at the top), so the spans of one
+served batch are grouped by cause, not by time.  :mod:`repro.obs.export`
+renders the recorded list as Chrome trace-event JSON for Perfetto.
 
-When a JAX profiler trace is active, every span also enters a
-``jax.profiler.TraceAnnotation`` of the same name, so host spans line up
-against device timelines in TensorBoard/XPlane captures; with no active
-profiler the annotation is a few-ns no-op.
+One clock with the device trace: every span also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so the host plane of an
+active profiler trace holds each span too.  That plane runs on
+``time.time_ns()``; a span's ``t0`` runs on ``time.perf_counter()``.  Each
+recorder reads both clocks once, at creation, and
+:meth:`SpanRecorder.to_trace_ns` maps any ``t0`` onto the profiler's clock
+with that one anchor — no second clock read per span.  A trace file's
+planes count from their session's ``profile_start_time`` (a stat of its
+``Task Environment`` plane): subtract it to place a span on a loaded
+timeline.
 
 Determinism contract: wall-clock readings stay inside the ``t0``/``dur``
-fields (exported as Chrome ``ts``/``dur``); span names, categories, lanes
-and args must be derived from deterministic run state only — the
+fields (exported as Chrome ``ts``/``dur``); span names, categories, lanes,
+sids and args must be derived from deterministic run state only — the
 trace-determinism test masks exactly ``ts``/``dur`` and pins the rest.
 
 Instrumented code calls the module-level :func:`span`, which records into
 the installed default recorder (a bounded deque, enabled from the start so
-ad-hoc profiling needs no setup).  Drivers that want an isolated capture
-install their own recorder via ``recording()``::
+ad-hoc profiling needs no setup).  ``with span(...) as h`` gives the open
+span's handle: ``h.sid`` on entry, ``h.dur`` (seconds) after exit — None
+where the recorder is disabled, which records and times nothing.  Drivers
+that want an isolated capture install their own recorder via
+``recording()``::
 
     with recording() as rec:
         run()
@@ -31,6 +43,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import itertools
 import threading
 import time
 from typing import NamedTuple, Optional
@@ -38,7 +51,7 @@ from typing import NamedTuple, Optional
 try:  # pragma: no cover - import guard, exercised implicitly
     from jax.profiler import TraceAnnotation as _TraceAnnotation
 except Exception:  # pragma: no cover - jax always present in this repo
-    _TraceAnnotation = None
+    _TraceAnnotation = contextlib.nullcontext
 
 
 class Span(NamedTuple):
@@ -49,15 +62,81 @@ class Span(NamedTuple):
     lane: int          # small stable per-thread index (first-seen order)
     depth: int         # nesting depth within the lane
     args: dict         # deterministic metadata only (no wall-clock)
+    sid: int = -1      # per-recorder span id, in entry order
+    parent: int = -1   # sid of the enclosing span on this thread, or -1
+
+
+class SpanHandle:
+    """One span while it is open: ``sid`` and ``parent`` from entry,
+    ``dur`` (seconds) once it has exited."""
+
+    __slots__ = ("_rec", "_name", "_cat", "_args", "_ann", "_tls", "sid",
+                 "parent", "depth", "t0", "dur")
+
+    def __init__(self, rec: "SpanRecorder", name: str, cat: str,
+                 args: dict):
+        self._rec, self._name, self._cat, self._args = rec, name, cat, args
+        self.dur: Optional[float] = None
+
+    def __enter__(self) -> "SpanHandle":
+        self._tls = tls = self._rec._tls
+        stack = tls.stack
+        self.depth = len(stack)
+        self.parent = stack[-1] if stack else -1
+        self.sid = sid = next(self._rec._sids)   # atomic under the GIL
+        stack.append(sid)
+        self._ann = ann = _TraceAnnotation(self._name)
+        self.t0 = time.perf_counter()
+        ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        try:
+            self._ann.__exit__(*exc)
+        finally:
+            self.dur = time.perf_counter() - self.t0
+            tls, rec = self._tls, self._rec
+            tls.stack.pop()
+            if tls.lane is None:
+                tls.lane = rec._lane()  # before taking _lock: not reentrant
+            span = Span(self._name, self._cat, self.t0, self.dur, tls.lane,
+                        self.depth, self._args, self.sid, self.parent)
+            with rec._lock:
+                rec._spans.append(span)
+
+
+class _Off:
+    """The handle of a disabled recorder: records and times nothing."""
+
+    sid = parent = -1
+    dur = None
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+
+_OFF = _Off()
+
+
+class _Local(threading.local):
+    """A recorder's per-thread state: the stack of open sids, and the
+    thread's lane once it has recorded a span."""
+
+    def __init__(self):
+        self.stack: list = []
+        self.lane: Optional[int] = None
 
 
 class SpanRecorder:
     """Bounded, thread-safe span sink.
 
     ``maxlen`` bounds memory for long-lived processes (old spans fall off);
-    per-thread nesting depth is tracked thread-locally, and thread idents
-    are normalized to dense ``lane`` indices in first-seen order so exports
-    do not leak nondeterministic OS thread ids.
+    per-thread nesting (depth and parent) is tracked thread-locally, and
+    thread idents are normalized to dense ``lane`` indices in first-seen
+    order so exports do not leak nondeterministic OS thread ids.
     """
 
     def __init__(self, maxlen: int = 65536, enabled: bool = True):
@@ -65,7 +144,17 @@ class SpanRecorder:
         self._spans = collections.deque(maxlen=maxlen)
         self._lock = threading.Lock()
         self._lanes: dict = {}
-        self._tls = threading.local()
+        self._tls = _Local()
+        self._sids = itertools.count()
+        # the clock anchor: perf_counter bracketing one time_ns reading
+        a = time.perf_counter()
+        self._anchor_ns = time.time_ns()
+        self._anchor_pc = 0.5 * (a + time.perf_counter())
+
+    def to_trace_ns(self, t: float) -> int:
+        """``t``, a ``time.perf_counter()`` reading such as a span's
+        ``t0``, on the profiler's host clock (``time.time_ns()``)."""
+        return self._anchor_ns + round((t - self._anchor_pc) * 1e9)
 
     def _lane(self) -> int:
         ident = threading.get_ident()
@@ -75,26 +164,11 @@ class SpanRecorder:
                 lane = self._lanes.setdefault(ident, len(self._lanes))
         return lane
 
-    @contextlib.contextmanager
     def span(self, name: str, cat: str = "host", **args):
+        """A context manager timing one span; ``as`` gives its handle."""
         if not self.enabled:
-            yield self
-            return
-        depth = getattr(self._tls, "depth", 0)
-        self._tls.depth = depth + 1
-        ann = (_TraceAnnotation(name) if _TraceAnnotation is not None
-               else contextlib.nullcontext())
-        t0 = time.perf_counter()
-        try:
-            with ann:
-                yield self
-        finally:
-            dur = time.perf_counter() - t0
-            self._tls.depth = depth
-            lane = self._lane()        # before taking _lock: not reentrant
-            with self._lock:
-                self._spans.append(
-                    Span(name, cat, t0, dur, lane, depth, dict(args)))
+            return _OFF
+        return SpanHandle(self, name, cat, args)
 
     def spans(self) -> list:
         with self._lock:

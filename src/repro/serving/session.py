@@ -31,7 +31,6 @@ shard_map'd multi-chip search with per-query conformal offset rows.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -188,9 +187,17 @@ class _PendingDist:
         return self
 
     def result(self) -> _DistResult:
-        return _DistResult(dists=np.asarray(self.nn)[:, None],
-                           searched=np.asarray(self.n_searched),
-                           n_leaves=self.n_leaves)
+        """Blocks on the device; spans as ``search.PendingSearch.result``:
+        ``search.wait``, then ``search.fetch``."""
+        out = [self.nn, self.n_searched]
+        with span("search.wait", cat="search", q=int(self.nn.shape[0]),
+                  k=1):
+            jax.block_until_ready(out)
+        with span("search.fetch", cat="search", n_arrays=len(out),
+                  bytes=int(sum(x.nbytes for x in out))):
+            return _DistResult(dists=np.asarray(self.nn)[:, None],
+                               searched=np.asarray(self.n_searched),
+                               n_leaves=self.n_leaves)
 
 
 class DistributedExecutor:
@@ -407,15 +414,16 @@ class ServingSession:
         *commits* staged results from batches ``<= seq − 1 − warm_lag``
         (identical in serial and pipelined serving — see the class
         docstring), then seeds this batch's prune-only bounds.  Host-side
-        cost (offset lowering + program submit) is recorded as the ``form``
-        latency phase; per-request queue waits (arrival → batch formation,
-        virtual clock) ride along.
+        cost (offset lowering + program submit), the duration of the
+        ``serve.dispatch`` span, is recorded as the ``form`` latency
+        phase; per-request queue waits (arrival → batch formation, virtual
+        clock) ride along.
         """
-        t0 = time.perf_counter()
         seq = self._seq
         self._seq += 1
         with span("serve.dispatch", cat="serve", seq=seq,
-                  bucket=batch.bucket, n_valid=batch.n_valid, k=batch.k):
+                  bucket=batch.bucket, n_valid=batch.n_valid,
+                  k=batch.k) as sp:
             bsf_ub = None
             if self.warm_start:
                 self.warm_cache.commit_through(seq - 1 - self.warm_lag)
@@ -424,17 +432,19 @@ class ServingSession:
                                          batch.k, bsf_ub=bsf_ub)
         self.telemetry.record_phases(
             queue_wait=(batch.formed_at - batch.arrivals).tolist(),
-            form_s=time.perf_counter() - t0)
+            form_s=sp.dur)
         return PendingBatch(pending=pending, batch=batch, seq=seq,
                             bsf_ub=bsf_ub)
 
     def harvest(self, pb: PendingBatch):
-        """Block on one dispatched batch; fold telemetry + warm staging."""
-        t0 = time.perf_counter()
+        """Block on one dispatched batch; fold telemetry + warm staging.
+
+        The ``serve.harvest`` span's duration is the ``exec`` latency
+        phase."""
         with span("serve.harvest", cat="serve", seq=pb.seq,
-                  bucket=pb.batch.bucket, n_valid=pb.batch.n_valid):
+                  bucket=pb.batch.bucket, n_valid=pb.batch.n_valid) as sp:
             res = pb.pending.result()
-        self.telemetry.record_phases(exec_s=time.perf_counter() - t0)
+        self.telemetry.record_phases(exec_s=sp.dur)
         b = pb.batch
         if self.warm_start:
             kth = np.asarray(res.dists)[:b.n_valid, -1]

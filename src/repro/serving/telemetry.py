@@ -154,14 +154,18 @@ class Telemetry:
         self._h_queue_wait = r.histogram(
             "serve_queue_wait_s", window=window,
             help="request arrival -> batch formation (virtual clock)")
-        # host wall-clock phases: segregated under the snapshot's "wall"
-        # subtree so determinism tests can mask them (see module docstring)
+        # host wall-clock phases, one timer each: the serve.dispatch and
+        # serve.harvest spans' durations (repro.obs.spans); segregated
+        # under the snapshot's "wall" subtree so determinism tests can
+        # mask them (see module docstring)
         self._h_form = r.histogram(
             "serve_form_s", window=window, wall=True,
-            help="host batch-formation + dispatch seconds per batch")
+            help="host dispatch seconds per batch: the serve.dispatch "
+                 "span (bounds, offsets, filter sweep, cascade enqueue)")
         self._h_exec = r.histogram(
             "serve_exec_s", window=window, wall=True,
-            help="device-execute / harvest-wait seconds per batch")
+            help="harvest seconds per batch: the serve.harvest span "
+                 "(device wait, device-to-host copies, id mapping)")
         self.drift = RecallDriftMonitor(
             r, window=drift_window, min_samples=drift_min_samples,
             slack=drift_slack, prefix="serve")
@@ -231,8 +235,10 @@ class Telemetry:
         """Fold one batch's latency-phase observations.
 
         ``queue_wait``: iterable of per-request waits (arrival → batch
-        formation, virtual clock); ``form_s``: host batch-formation +
-        dispatch seconds; ``exec_s``: device-execute / harvest-wait seconds.
+        formation, virtual clock); ``form_s``: the ``serve.dispatch``
+        span's seconds; ``exec_s``: the ``serve.harvest`` span's seconds
+        (device wait, copies, id mapping).  A phase given as None (a
+        disabled span recorder times nothing) is not observed.
         """
         if queue_wait is not None:
             self._h_queue_wait.extend(float(w) for w in queue_wait)
