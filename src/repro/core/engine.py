@@ -11,11 +11,18 @@ pattern.
 
 Two strategies over identical semantics:
 
-* ``strategy="scan"`` — the original masked ``lax.scan``: every leaf's
-  distances are computed and masked.  Wall-clock is O(all leaves) regardless
-  of how well the cascade prunes; kept as the validated fallback and as the
-  shard_map-safe form (compaction needs data-dependent shapes, which cannot
-  live under jit).
+* ``strategy="scan"`` — every leaf's distances are computed, in one jitted
+  fixed-shape program of two phases: a leaf-major pass reads each leaf's
+  slab once for the whole batch (a contiguous ``dynamic_slice`` at the
+  leaf's start; the series is leaf-sorted) and keeps its k smallest
+  distances per query, then :func:`replay_cascade` replays the exact
+  cascade over those summaries in each query's lower-bound order: the
+  compact strategy's phase 3 with every leaf a survivor and no host
+  round-trip.  Wall-clock is O(all leaves) regardless of how well the
+  cascade prunes; it is the shard_map-safe form (compaction needs
+  data-dependent shapes, which cannot live under jit).  The ``trace`` and
+  ``audit`` variants keep the sequential per-query cascade, which the
+  tests pin bitwise against the two-phase program.
 
 * ``strategy="compact"`` — three phases, so compute shrinks with the pruning
   ratio:
@@ -47,16 +54,18 @@ Two strategies over identical semantics:
        where ‖q‖²+‖s‖²−2qs cancels), the same trade the ``l2_scan`` kernel
        itself makes.
 
-Cost model: scan is Q·L·R·m multiply-adds (R = max leaf size); compact is
-Q·R·m (probe) + Σ_q C_q·R·m (candidates) + Q·L·k merge work, with C_q the
-survivor count — i.e. the heavy term scales with (1 − pruning ratio).
-Measured (benchmarks/engine_bench.py, CPU, 50k×128 randwalk, L=512, Q=32,
-k=5, experiments/engine_bench.json): scan stays flat at 206–225 ms across
-the sweep while compact tracks the pruning ratio — 158 ms at ratio 0.65
-(lower bounds only, 1.31×), 133 ms at 0.67 (1.68×), 44 ms at 0.88 (5.1×),
-29 ms at 0.97 (7.9×), 26 ms at 0.98 (8.5×).  In the adversarial
-all-leaves-survive case (tests/test_engine.py) compact degrades to
-scan-plus-probe-overhead instead of winning.
+Cost model: scan is Q·L·R·m multiply-adds (R = max leaf size) and reads
+L·R·m series values per batch (each leaf once; a per-query walk reads
+Q·L·R·m), plus Q·L·k merge work in the replay; compact is Q·R·m (probe) +
+Σ_q C_q·R·m (candidates) + Q·L·k merge work, with C_q the survivor count —
+i.e. the heavy term scales with (1 − pruning ratio).
+Measured with the earlier per-query scan (benchmarks/engine_bench.py, CPU,
+50k×128 randwalk, L=512, Q=32, k=5, experiments/engine_bench.json): scan
+stays flat at 206–225 ms across the sweep while compact tracks the pruning
+ratio — 158 ms at ratio 0.65 (lower bounds only, 1.31×), 133 ms at 0.67
+(1.68×), 44 ms at 0.88 (5.1×), 29 ms at 0.97 (7.9×), 26 ms at 0.98 (8.5×).
+In the adversarial all-leaves-survive case (tests/test_engine.py) compact
+degrades to scan-plus-probe-overhead instead of winning.
 
 The reported ``searched``/``pruned_*`` counters follow the paper's
 searched-leaf accounting of the sequential cascade (both strategies agree
@@ -110,6 +119,13 @@ _INF = jnp.float32(jnp.inf)
 # a larger target only adds memory traffic (measured 3× slower at 128 MiB).
 _CHUNK_BYTES = 4 << 20
 
+# leaves per loop step of the scan strategy's leaf-major pass (an unrolled
+# run; the work per leaf is unchanged).  Measured on a TPU v5e at both
+# benchmark cells' shapes, the pass plus replay took 430 → 333 ms
+# (Q=64, R=256, m=96, L=16,389, k=10) and 49 → 44 ms (Q=16, m=256,
+# L=4,097, k=1) from 1 to 4: the loop's per-step cost is amortised.
+_LEAVES_PER_STEP = 4
+
 
 @dataclasses.dataclass
 class EngineResult:
@@ -128,9 +144,38 @@ def _next_pow2(n: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# strategy="scan" — the original masked sequential cascade (fallback; also
-# the only jit-safe form, since compaction needs data-dependent shapes)
+# strategy="scan" — every leaf scored, no host round-trip (the jit-safe form,
+# since compaction needs data-dependent shapes)
 # ---------------------------------------------------------------------------
+
+
+def _leafwise_topk(series, leaf_start, leaf_size, queries, kk, max_leaf):
+    """Every leaf's kk smallest distances to every query, leaf-major.
+
+    ``_LEAVES_PER_STEP`` leaves per loop step; each leaf's slab is one
+    contiguous ``dynamic_slice`` at a scalar start (the series is
+    leaf-sorted), read once for the whole batch and scored in the direct
+    form of the sequential cascade.  A slab gathered per query instead
+    lowers on the TPU to one serial copy per query.  Returns (vals (Q, L,
+    kk), ids (Q, L, kk)): +inf / −1 past a leaf's size, ties toward the
+    lower row (``l2_ops.leaf_topk``'s order).
+    """
+    row_ids = jnp.arange(max_leaf)
+
+    def step(_, leaf):
+        start = leaf_start[leaf]
+        slab = jax.lax.dynamic_slice_in_dim(series, start, max_leaf, 0)
+        diff = slab[None] - queries[:, None, :]
+        d = jnp.sqrt((diff * diff).sum(-1))                   # (Q, R)
+        d = jnp.where(row_ids < leaf_size[leaf], d, _INF)
+        neg, arg = jax.lax.top_k(-d, kk)
+        ids = jnp.where(jnp.isfinite(neg), start + arg, -1)
+        return None, (-neg, ids.astype(jnp.int32))
+
+    _, (vals, ids) = jax.lax.scan(step, None,
+                                  jnp.arange(leaf_start.shape[0]),
+                                  unroll=_LEAVES_PER_STEP)
+    return vals.transpose(1, 0, 2), ids.transpose(1, 0, 2)
 
 
 @functools.partial(jax.jit,
@@ -138,74 +183,30 @@ def _next_pow2(n: int) -> int:
 def _scan_cascade(series, leaf_start, leaf_size, queries, d_lb, d_F,
                   bsf_ub, k, max_leaf, trace=False, audit=False):
     order = jnp.argsort(d_lb, axis=1)
+    if not (trace or audit):
+        # the compact strategy's two phases with every leaf a survivor:
+        # each leaf's k smallest for the whole batch, then the exact
+        # cascade replayed over them in each query's lb order.
+        leaf_d, leaf_i = _leafwise_topk(series, leaf_start, leaf_size,
+                                        queries, min(k, max_leaf), max_leaf)
+        return _replay_cascade(leaf_d, leaf_i, d_lb, d_F, order, k=k,
+                               bsf_ub=bsf_ub)
+
+    # the instrumented variants keep the sequential per-query cascade; the
+    # parity tests pin it bitwise against the two-phase program above.
     row_ids = jnp.arange(max_leaf)
     L = d_lb.shape[1]
 
     def per_query(q, lb_row, dF_row, order_row, ub):
         def step(carry, leaf):
-            topk_d, topk_i, n_s, n_plb, n_pf = carry
-            # lb-prune against min(bsf, ub): ub is a proven upper bound on
-            # the true k-th NN distance (see run_cascade's bsf_ub contract),
-            # so a leaf with lb > min(bsf, ub) holds no top-k member —
-            # pruning it cannot change the answer, only the searched count.
-            # The learned-filter test stays against the witnessed bsf only:
-            # conformal offsets are calibrated against the unseeded cascade
-            # (where the best-lb leaf is visited at bsf = INF), so tightening
-            # d_F's threshold with ub would break the recall contract.
-            bsf = topk_d[-1]
-            p_lb = lb_row[leaf] > jnp.minimum(bsf, ub)
-            p_f = jnp.logical_and(~p_lb, dF_row[leaf] > bsf)
-            pruned = p_lb | p_f
-            start = leaf_start[leaf]
-            slab = jax.lax.dynamic_slice_in_dim(series, start, max_leaf, 0)
-            diff = slab - q[None, :]
-            d = jnp.sqrt((diff * diff).sum(-1))
-            d = jnp.where((row_ids < leaf_size[leaf]) & ~pruned, d, _INF)
-            ids = (start + row_ids).astype(jnp.int32)
-            alld = jnp.concatenate([topk_d, d])
-            alli = jnp.concatenate([topk_i, ids])
-            neg_top, arg = jax.lax.top_k(-alld, k)
-            return (-neg_top, alli[arg],
-                    n_s + (~pruned).astype(jnp.int32),
-                    n_plb + p_lb.astype(jnp.int32),
-                    n_pf + p_f.astype(jnp.int32)), None
-
-        def step_traced(carry, leaf):
-            # mirrors `step` exactly (the bitwise parity test in
-            # tests/test_engine.py enforces the mirror), plus three
-            # masked-sum counters: box/seed split of the lb prune and the
-            # exact distance rows consulted.
-            topk_d, topk_i, n_s, n_plb, n_pf, n_box, n_seed, n_rows = carry
-            bsf = topk_d[-1]
-            p_lb = lb_row[leaf] > jnp.minimum(bsf, ub)
-            p_box = lb_row[leaf] > bsf
-            p_seed = jnp.logical_and(p_lb, ~p_box)
-            p_f = jnp.logical_and(~p_lb, dF_row[leaf] > bsf)
-            pruned = p_lb | p_f
-            start = leaf_start[leaf]
-            slab = jax.lax.dynamic_slice_in_dim(series, start, max_leaf, 0)
-            diff = slab - q[None, :]
-            d = jnp.sqrt((diff * diff).sum(-1))
-            d = jnp.where((row_ids < leaf_size[leaf]) & ~pruned, d, _INF)
-            ids = (start + row_ids).astype(jnp.int32)
-            alld = jnp.concatenate([topk_d, d])
-            alli = jnp.concatenate([topk_i, ids])
-            neg_top, arg = jax.lax.top_k(-alld, k)
-            rows = jnp.where(pruned, 0, leaf_size[leaf]).astype(jnp.int32)
-            return (-neg_top, alli[arg],
-                    n_s + (~pruned).astype(jnp.int32),
-                    n_plb + p_lb.astype(jnp.int32),
-                    n_pf + p_f.astype(jnp.int32),
-                    n_box + p_box.astype(jnp.int32),
-                    n_seed + p_seed.astype(jnp.int32),
-                    n_rows + rows), None
-
-        def step_audit(carry, leaf):
-            # mirrors `step_traced` exactly and additionally emits the
-            # per-leaf decision planes (visit order) for the FilterAudit
-            # reduction: d.min() over the masked slab is the leaf's exact
-            # NN distance when scanned — a free byproduct of the distance
-            # pass — and +inf when pruned.
+            # the replay's decisions (see _replay_cascade for why ub enters
+            # the lb test only) over the leaf's full distance block, plus
+            # three masked-sum counters (box/seed split of the lb prune and
+            # the exact distance rows consulted) and the per-leaf decision
+            # planes (visit order) for the FilterAudit reduction: d.min()
+            # over the masked slab is the leaf's exact NN distance when
+            # scanned — a free byproduct of the distance pass — and +inf
+            # when pruned.
             topk_d, topk_i, n_s, n_plb, n_pf, n_box, n_seed, n_rows = carry
             bsf = topk_d[-1]
             p_lb = lb_row[leaf] > jnp.minimum(bsf, ub)
@@ -232,31 +233,22 @@ def _scan_cascade(series, leaf_start, leaf_size, queries, d_lb, d_F,
                     n_seed + p_seed.astype(jnp.int32),
                     n_rows + rows), ys
 
-        if audit:
-            init = (jnp.full((k,), _INF), jnp.full((k,), -1, jnp.int32),
-                    jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                    jnp.int32(0), jnp.int32(0), jnp.int32(0))
-            out, ys = jax.lax.scan(step_audit, init, order_row)
-            vb, vs, vf, vk, vnn = ys              # (L,) in visit order
-
-            def scat(v, fill):
-                base = jnp.full((L,), fill, v.dtype)
-                return base.at[order_row].set(v)  # order is a permutation
-
-            parts = AuditParts(scat(vb, False), scat(vs, False),
-                               scat(vf, False), scat(vk, False),
-                               scat(vk, False), scat(vnn, _INF))
-            return out + (parts,)
-        if trace:
-            init = (jnp.full((k,), _INF), jnp.full((k,), -1, jnp.int32),
-                    jnp.int32(0), jnp.int32(0), jnp.int32(0),
-                    jnp.int32(0), jnp.int32(0), jnp.int32(0))
-            out, _ = jax.lax.scan(step_traced, init, order_row)
-            return out
         init = (jnp.full((k,), _INF), jnp.full((k,), -1, jnp.int32),
+                jnp.int32(0), jnp.int32(0), jnp.int32(0),
                 jnp.int32(0), jnp.int32(0), jnp.int32(0))
-        (td, ti, n_s, n_plb, n_pf), _ = jax.lax.scan(step, init, order_row)
-        return td, ti, n_s, n_plb, n_pf
+        out, ys = jax.lax.scan(step, init, order_row)
+        if not audit:
+            return out
+        vb, vs, vf, vk, vnn = ys                  # (L,) in visit order
+
+        def scat(v, fill):
+            base = jnp.full((L,), fill, v.dtype)
+            return base.at[order_row].set(v)      # order is a permutation
+
+        parts = AuditParts(scat(vb, False), scat(vs, False),
+                           scat(vf, False), scat(vk, False),
+                           scat(vk, False), scat(vnn, _INF))
+        return out + (parts,)
 
     return jax.vmap(per_query)(queries, d_lb, d_F, order, bsf_ub)
 
@@ -308,15 +300,17 @@ def _replay_cascade(leaf_d, leaf_i, d_lb, d_F, order, k, bsf0=None,
                     leaf_valid=None, bsf_ub=None, trace=False):
     """Jitted body of :func:`replay_cascade` — see the wrapper's docstring.
 
-    Identical decision logic and merge arithmetic to ``_scan_cascade`` — the
-    k smallest of (running top-k ∪ a leaf's k smallest) equal the k smallest
-    of (running top-k ∪ all the leaf's distances), and ties resolve the same
-    way because the running top-k precedes the leaf block in both concats —
-    but each step merges k values instead of computing max_leaf·m distances.
+    Identical decision logic and merge arithmetic to the sequential cascade
+    (``_scan_cascade``'s instrumented variants) — the k smallest of (running
+    top-k ∪ a leaf's k smallest) equal the k smallest of (running top-k ∪
+    all the leaf's distances), and ties resolve the same way because the
+    running top-k precedes the leaf block in both concats — but each step
+    merges k values instead of computing max_leaf·m distances.
 
     This is the single copy of the bsf cascade's decision logic: the compact
-    search strategy runs it over gathered candidate summaries, conformal
-    calibration (``conformal.simulate_search``) runs it with k=1 over the
+    search strategy runs it over gathered candidate summaries, the scan
+    strategy over every leaf's summaries, conformal calibration
+    (``conformal.simulate_search``) runs it with k=1 over the
     precollected d_L matrices, and the distributed fixed-width compaction
     (``compact_bsf_cascade``) runs it with k=1 from a collective bsf seed —
     no series data touched.
@@ -340,8 +334,14 @@ def _replay_cascade(leaf_d, leaf_i, d_lb, d_F, order, k, bsf0=None,
     def per_query(ld, li, lb_row, dF_row, order_row, b0, ub):
         def step(carry, leaf):
             topk_d, topk_i, n_s, n_plb, n_pf = carry
-            # ub tightens the lb test only; d_F compares against the
-            # witnessed bsf (see _scan_cascade for why).
+            # lb-prune against min(bsf, ub): ub is a proven upper bound on
+            # the true k-th NN distance (see run_cascade's bsf_ub contract),
+            # so a leaf with lb > min(bsf, ub) holds no top-k member —
+            # pruning it cannot change the answer, only the searched count.
+            # The learned-filter test stays against the witnessed bsf only:
+            # conformal offsets are calibrated against the unseeded cascade
+            # (where the best-lb leaf is visited at bsf = INF), so tightening
+            # d_F's threshold with ub would break the recall contract.
             bsf = topk_d[-1]
             p_lb = jnp.logical_or(lb_row[leaf] > jnp.minimum(bsf, ub),
                                   invalid[leaf])
@@ -401,9 +401,10 @@ def replay_cascade(leaf_d, leaf_i, d_lb, d_F, order, k, bsf0=None,
 
     The single copy of the bsf cascade's decision logic (see
     :func:`_replay_cascade` for the merge-equivalence argument): the compact
-    search strategy runs it over gathered candidate summaries, conformal
-    calibration (``conformal.simulate_search``) runs it with k=1 over the
-    precollected d_L matrices, and the distributed fixed-width compaction
+    search strategy runs it over gathered candidate summaries, the scan
+    strategy over every leaf's summaries, conformal calibration
+    (``conformal.simulate_search``) runs it with k=1 over the precollected
+    d_L matrices, and the distributed fixed-width compaction
     (``compact_bsf_cascade``) runs it with k=1 from a collective bsf seed.
     Under ``REPRO_CHECKIFY=1`` eager calls run checkify-instrumented
     (``repro.sanitize``); traced calls pass straight through.
@@ -681,7 +682,8 @@ def run_cascade(
     """Batched top-k leaf-cascade search over precomputed pruning inputs.
 
     strategy: "compact" (default via "auto") computes distances only for
-    cascade survivors; "scan" is the masked sequential fallback.  With
+    cascade survivors; "scan" scores every leaf in one fixed-shape program
+    (the jit-safe fallback).  With
     ``dist_impl="direct"`` (the off-TPU default) both strategies return
     bitwise-identical results; on TPU the default is "matmul" (the
     pairwise-L2 kernel's decomposition, MXU-tiled), which matches scan only
@@ -710,17 +712,21 @@ def run_cascade(
     :class:`~repro.obs.trace.CascadeTrace` on ``EngineResult.trace``
     (which bound pruned which leaf, survivors, exact distance rows paid —
     see ``repro.obs.trace`` for the attribution semantics and the
-    accounting identity).  Results are bitwise-identical either way, and
-    ``trace=False`` lowers to the byte-identical program (the flag is a
-    Python-level branch on extra masked-sum counters only).
+    accounting identity).  Results are bitwise-identical either way.  On
+    "compact" the flag is a Python-level branch on extra masked-sum
+    counters only, so ``trace=False`` lowers to the byte-identical program;
+    on "scan" the instrumented run is the sequential per-query cascade and
+    the untraced one a different program (leaf-major pass + replay) with
+    bitwise-equal results.
     audit: static flag; True additionally returns a per-leaf
     :class:`~repro.obs.audit.FilterAudit` on ``EngineResult.audit`` —
     prune counts by bound, work saved, and prediction-residual statistics
     (``true_leaf_nn − d_F``) for the leaves the engine scored exactly, at
     zero extra distance computations (see ``repro.obs.audit`` for the
     residual semantics and the per-leaf accounting identity).  Same
-    discipline as ``trace``: results are bitwise-identical either way and
-    ``audit=False`` lowers to the byte-identical program.
+    discipline as ``trace``: results are bitwise-identical either way, and
+    on "scan" ``audit=True`` runs the same sequential cascade as
+    ``trace=True``.
     """
     if strategy == "auto":
         strategy = "compact"

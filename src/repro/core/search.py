@@ -8,11 +8,12 @@ Two execution styles over the same semantics:
   runs through :mod:`repro.core.engine`.  The default ``strategy="compact"``
   computes distances only for cascade survivors (prune → compact → batched
   MXU candidate pass), so wall-clock shrinks with the pruning ratio;
-  ``strategy="scan"`` is the validated masked-``lax.scan`` fallback that
-  computes every leaf.  Both report the paper's hardware-agnostic cost
-  metric (searched-leaf count) exactly and return identical results —
-  bitwise with the ``direct`` distance impl (the off-TPU default), to float
-  tolerance with the TPU-default ``matmul`` impl (see the engine module).
+  ``strategy="scan"`` is the fixed-shape fallback that computes every
+  leaf (each read once per batch, then the same replay).  Both report
+  the paper's hardware-agnostic cost metric (searched-leaf count) exactly
+  and return identical results — bitwise with the ``direct`` distance impl
+  (the off-TPU default), to float tolerance with the TPU-default ``matmul``
+  impl (see the engine module).
 
 * ``search_early`` — latency form for a single query: a while_loop that
   terminates at the first lower bound exceeding d_bsf (visiting in LB order

@@ -98,6 +98,59 @@ def test_k_larger_than_leaf_capacity(index_small, queries_small):
     _assert_bitwise(a, b)
 
 
+def _scan(index, queries, d_lb, d_F, k, bsf_ub, trace):
+    return engine.run_cascade(
+        jnp.asarray(index.series), jnp.asarray(index.leaf_start),
+        jnp.asarray(index.leaf_size), queries, d_lb, d_F,
+        k=k, max_leaf=index.max_leaf_size, strategy="scan", bsf_ub=bsf_ub,
+        trace=trace)
+
+
+@pytest.mark.parametrize("k", [1, 10, "over_leaf"])
+def test_leafwise_scan_matches_sequential_scan_bitwise(
+        index_small, queries_small, k):
+    """The untraced scan (every leaf's k smallest read leaf-major once for
+    the batch, then the replay) == the sequential per-query cascade that
+    trace=True keeps == compact with the direct distance form, bitwise,
+    with filters pruning, a finite warm-start bound and a batch of 11."""
+    q = jnp.asarray(queries_small[:11])
+    if k == "over_leaf":
+        k = index_small.max_leaf_size + 17
+    d_lb = bounds.lower_bounds(index_small, q)
+    d_F = _synthetic_predictions(d_lb, seed=5)
+    want_d, _ = _brute_force(index_small, np.asarray(q), k)
+    ub = jnp.asarray(want_d[:, -1] * np.float32(1.05))
+    a = _scan(index_small, q, d_lb, d_F, k, ub, trace=False)
+    b = _scan(index_small, q, d_lb, d_F, k, ub, trace=True)
+    c = engine.run_cascade(
+        jnp.asarray(index_small.series), jnp.asarray(index_small.leaf_start),
+        jnp.asarray(index_small.leaf_size), q, d_lb, d_F,
+        k=k, max_leaf=index_small.max_leaf_size, strategy="compact",
+        dist_impl="direct", bsf_ub=ub)
+    assert np.asarray(b.n_pruned_filter).sum() > 0   # the cascade is active
+    _assert_bitwise(a, b)
+    _assert_bitwise(a, c)
+
+
+def test_untraced_scan_gathers_no_series_slab(index_small, queries_small):
+    """The mechanism: the untraced scan reads the series only through
+    dynamic slices at a scalar (per-leaf) start.  A gather whose operand is
+    the series is what the chip runs as one serial copy per query."""
+    q = jnp.asarray(queries_small[:5])
+    series = jnp.asarray(index_small.series)
+    d_lb = bounds.lower_bounds(index_small, q)
+    txt = engine._scan_cascade.lower(
+        series, jnp.asarray(index_small.leaf_start),
+        jnp.asarray(index_small.leaf_size), q, d_lb,
+        jnp.full(d_lb.shape, -jnp.inf), jnp.full((5,), jnp.inf),
+        k=3, max_leaf=index_small.max_leaf_size).as_text()
+    series_t = "tensor<{}x{}xf32>".format(*series.shape)
+    gathers = [ln for ln in txt.splitlines() if "stablehlo.gather" in ln]
+    assert gathers                       # the small per-query gathers stay
+    assert not [ln for ln in gathers if f": ({series_t}," in ln]
+    assert "stablehlo.dynamic_slice" in txt
+
+
 def _brute_force(index, queries, k):
     S = np.asarray(index.series[: index.n_series])
     d = np.sqrt(((queries[:, None, :] - S[None]) ** 2).sum(-1))
