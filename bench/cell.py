@@ -12,7 +12,9 @@ files and entries only:
   returning a number or None (nothing to read: the metric is left out).
   Where that file is absent, the reader of the name's stem serves it:
   ``compiles.py`` reads ``compiles.open`` and ``compiles.bulk``, the one
-  quantity split by the end-to-end metric it moves.
+  quantity split by the end-to-end metric it moves.  A reader that reads
+  nothing where the trace holds no device operation says so with
+  ``CHIP_ONLY = True``; the CPU tests expect every other metric of a cell.
 """
 from __future__ import annotations
 
@@ -139,8 +141,20 @@ def _drive(session, collection, traffic, seconds, s_queries):
             session, lambda c: gen.make_queries(collection, c, t["noise"],
                                                 rng),
             k=t["k"], outstanding=t["outstanding"],
-            max_batch=t["max_batch"], seconds=seconds)
+            max_batch=t["max_batch"], seconds=seconds,
+            draw_targets=closed_targets(t))
     raise CellError(f"unknown loop {t['loop']!r}")
+
+
+def closed_targets(traffic):
+    """``c`` ↦ the recall targets of a closed loop's next ``c`` requests,
+    uniform over the traffic's targets and drawn from its ``target_seed``,
+    so every run offers the same sequence (None: the requests are exact)."""
+    if traffic["targets"] is None:
+        return None
+    rng = np.random.default_rng(traffic["target_seed"])
+    choices = np.asarray(traffic["targets"], np.float64)
+    return lambda c: choices[rng.integers(0, len(choices), c)]
 
 
 def warm_up(session, collection, traffic, s_queries) -> None:
@@ -321,11 +335,13 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
                         "hi": hi, "busy_ns": busy, "planes": planes}
         print(f"bench: trace: {len(dev_ev)} device events on {planes}, "
               f"{len(ev['host'])} host events", file=out)
+        # the part of the window the device record covers
+        cut = tracing.recorded_until(dev_ev, hi)
         result["device"]["busy_s"] = busy * 1e-9
-        result["device"]["window_s"] = (hi - lo) * 1e-9
+        result["device"]["window_s"] = (cut - lo) * 1e-9
         result["breakdown"] = {
-            "device_ops": tracing.top_ops(dev_ev, lo, hi),
-            "idle_gaps": tracing.idle_gaps(dev_ev, ev["host"], lo, hi)}
+            "device_ops": tracing.top_ops(dev_ev, lo, cut),
+            "idle_gaps": tracing.idle_gaps(dev_ev, ev["host"], lo, cut)}
     for mt in spec["per_layer" if trace else "end_to_end"]:
         v = reader(spec["metrics_dir"], mt["name"])(ctx)
         if v is not None:

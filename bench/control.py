@@ -6,10 +6,11 @@ lower, put in the program's place, has to come out as not correct.
 
 For each seed it makes the run's collection and the window's queries as
 ``run.py`` does (an open loop's requests due in ``--seconds``; a closed
-loop's first ``--queries``), answers them with ``reference.control_knn``
-and compares those answers with the float32 reference exactly as a run is
-compared.  It prints one JSON line per seed with the numbers compared and
-``correct``.  The program is not built: the control replaces it.
+loop's first ``--queries``, with their targets where it has any), answers
+them with ``reference.control_knn`` and compares those answers with the
+float32 reference exactly as a run is compared.  It prints one JSON line
+per seed with the numbers compared and ``correct``.  The program is not
+built: the control replaces it.
 """
 from __future__ import annotations
 
@@ -36,7 +37,9 @@ def control_run(root: str, workload: str, seed: int, seconds: float,
     else:
         queries = gen.make_queries(collection, n_queries, traffic["noise"],
                                    np.random.default_rng(s_q))
-        due, targets = np.zeros(n_queries), None
+        draw_targets = cell.closed_targets(traffic)
+        due = np.zeros(n_queries)
+        targets = None if draw_targets is None else draw_targets(n_queries)
     n, k = len(queries), traffic["k"]
     served = loops.Served(
         queries=queries, targets=targets, due=due, done=np.zeros(n),

@@ -11,8 +11,10 @@ Both drive the program's served path and own nothing of it but the clock:
 * :func:`closed_loop` — callers that each wait on their answer:
   ``outstanding`` requests are open at all times; batches of up to
   ``max_batch`` are taken first in, first out, and every answer issues the
-  caller's next request.  The requests are exact and go through
-  ``ServingSession.search_exact``.
+  caller's next request.  Exact requests go through
+  ``ServingSession.search_exact``; requests with a recall target go
+  through ``ServingSession.search`` with the batch's per-query targets
+  and, as ``search_exact``, ``record=False``.
 
 Each returns a :class:`Served` record of every request it issued.
 """
@@ -132,13 +134,16 @@ def open_loop(session, queries: np.ndarray, due: np.ndarray,
 
 def closed_loop(session, draw: Callable[[int], np.ndarray], *, k: int,
                 outstanding: int, max_batch: int, seconds: float,
+                draw_targets: Optional[Callable[[int], np.ndarray]] = None,
                 clock: Callable[[], float] = time.perf_counter) -> Served:
-    """Keep ``outstanding`` exact requests open for ``seconds``.
+    """Keep ``outstanding`` requests open for ``seconds``.
 
-    ``draw(c)`` returns the next ``c`` queries.  No batch starts after
-    ``seconds``; the window ends when the last one started is answered.
+    ``draw(c)`` returns the next ``c`` queries and ``draw_targets(c)`` their
+    recall targets; without ``draw_targets`` every request is exact.  No
+    batch starts after ``seconds``; the window ends when the last one
+    started is answered.
     """
-    qs, issued = [], []
+    qs, ts, issued = [], [], []
     done_l, ids_l, dists_l, searched_l = [], [], [], []
     batches: List[dict] = []
     queue: deque = deque()
@@ -146,6 +151,8 @@ def closed_loop(session, draw: Callable[[int], np.ndarray], *, k: int,
     t0 = clock()
 
     def issue(t, c):
+        if draw_targets is not None:
+            ts.extend(draw_targets(c))
         for row in draw(c):
             queue.append(len(qs))
             qs.append(row)
@@ -160,8 +167,14 @@ def closed_loop(session, draw: Callable[[int], np.ndarray], *, k: int,
         take = [queue.popleft() for _ in range(min(max_batch, len(queue)))]
         qb = np.stack([qs[r] for r in take])
         d0 = clock()
-        with TraceAnnotation("bench.search_exact"):
-            res = session.search_exact(qb, k=k)
+        if draw_targets is None:
+            with TraceAnnotation("bench.search_exact"):
+                res = session.search_exact(qb, k=k)
+        else:
+            tb = np.asarray([ts[r] for r in take], np.float64)
+            with TraceAnnotation("bench.search"):
+                res = session.search(qb, quality_targets=tb, k=k,
+                                     record=False)
         t = clock() - t0
         batches.append({"bucket": len(take), "n_valid": len(take),
                         "dispatch_s": t - (d0 - t0), "done": t})
@@ -178,7 +191,8 @@ def closed_loop(session, draw: Callable[[int], np.ndarray], *, k: int,
     for r in range(n):
         if ids_l[r] is not None:
             ids[r], dists[r] = ids_l[r], dists_l[r]
-    return Served(queries=np.stack(qs), targets=None,
+    targets = None if draw_targets is None else np.asarray(ts, np.float64)
+    return Served(queries=np.stack(qs), targets=targets,
                   due=np.asarray(issued), done=np.asarray(done_l),
                   ids=ids, dists=dists, searched=np.asarray(searched_l),
                   n_leaves=n_leaves, batches=batches,

@@ -3,6 +3,8 @@ the window: the device-to-host copies of the answer (the span's ``n_arrays``
 and ``bytes``) and the id mapping.  Nothing to read where the trace holds no
 device operation: on the CPU nothing is copied from a chip."""
 
+CHIP_ONLY = True
+
 
 def read(ctx):
     if not (ctx["trace"] and ctx["trace"]["device"]):

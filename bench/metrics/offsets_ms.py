@@ -3,6 +3,8 @@ inside the window: the conformal offsets of the batch's recall targets and
 their upload.  Nothing to read where the trace holds no device operation,
 or where no request carries a target (an exact loop has no such span)."""
 
+CHIP_ONLY = True
+
 
 def read(ctx):
     if not (ctx["trace"] and ctx["trace"]["device"]):

@@ -3,6 +3,8 @@ mean ``search.wait`` span (``PendingSearch.result``'s block on every output
 it reads) whose start lies inside the window.  Nothing to read where the
 trace holds no device operation: a wait on the CPU is no wait for a chip."""
 
+CHIP_ONLY = True
+
 
 def read(ctx):
     if not (ctx["trace"] and ctx["trace"]["device"]):

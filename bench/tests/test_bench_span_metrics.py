@@ -13,9 +13,12 @@ from repro.obs.spans import Span
 
 METRICS = os.path.join(tiny.BENCH, "metrics")
 SPAN_METRICS = {"wait_ms.open": "search.wait", "wait_ms.bulk": "search.wait",
+                "wait_ms.approx": "search.wait",
                 "fetch_ms.open": "search.fetch",
                 "fetch_ms.bulk": "search.fetch",
-                "offsets_ms.open": "search.offsets"}
+                "fetch_ms.approx": "search.fetch",
+                "offsets_ms.open": "search.offsets",
+                "offsets_ms.approx": "search.offsets"}
 DEVICE = [("op", 100.0, 200.0), ("op", 150.0, 300.0), ("op", 600.0, 700.0)]
 
 
@@ -75,6 +78,23 @@ def test_wait_idle_stops_where_the_device_events_stop(metric):
             ("search.wait", 950.0, 1100.0)]
     assert read(_ctx(host=host)) == pytest.approx(100.0 * 150.0 / 400.0)
     assert read(_ctx(host=[("search.wait", 800.0, 1000.0)])) is None
+
+
+@pytest.mark.parametrize("metric", ["idle_share.open", "idle_share.bulk",
+                                    "idle_share.approx"])
+def test_idle_share_stops_where_the_device_events_stop(metric):
+    """Busy 250 ns of [0, 700): the record ends with the last device
+    operation, not with the window at 1000; a device record that reaches
+    past the window is read to the window's end."""
+    read = cell.reader(METRICS, metric)
+    ctx = _ctx()
+    ctx["trace"]["busy_ns"] = 250.0
+    assert read(ctx) == pytest.approx(100.0 * (1.0 - 250.0 / 700.0))
+    ctx["trace"]["device"] = DEVICE + [("op", 900.0, 1200.0)]
+    ctx["trace"]["busy_ns"] = 350.0
+    assert read(ctx) == pytest.approx(100.0 * (1.0 - 350.0 / 1000.0))
+    assert read({"trace": None}) is None
+    assert read(_ctx(device=())) is None
 
 
 @pytest.mark.parametrize("metric", ["wait_idle.open", "wait_idle.bulk"])

@@ -25,6 +25,12 @@ def test_busy_and_idle_share():
     assert 1 - busy / 1000 == pytest.approx(0.6)
 
 
+def test_record_ends_at_the_last_device_event():
+    assert tracing.recorded_until(DEV, 1000) == 1000     # fusion.9 is later
+    assert tracing.recorded_until(DEV[:3], 1000) == 700
+    assert tracing.recorded_until([], 1000) == 1000
+
+
 def test_top_ops_orders_by_device_time():
     top = tracing.top_ops(DEV, 0, 1000)
     assert [n for n, _ in top] == ["fusion.1",

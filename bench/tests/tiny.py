@@ -1,66 +1,118 @@
-"""A tiny benchmark tree for the CPU tests: one configuration, an open and
-a closed traffic mix and their cells, written beside the real metric
-readers, so the harness runs end to end in seconds."""
+"""A tiny twin of a benchmark tree for the CPU tests.
+
+``make_root`` mirrors every configuration, traffic mix and cell of a
+``BENCHMARK.json`` (the repository's own by default): each is shrunk by
+one rule and renamed ``tiny-<name>``, every metric's ``workloads`` list is
+renamed by the same rule, and the tree's metric readers are linked beside
+them.  So the harness runs every cell end to end on the CPU in seconds,
+and a cell added by data alone is tested the moment it is added.
+"""
+import copy
 import json
 import os
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
+REAL = os.path.join(ROOT, "BENCHMARK.json")
 
-CONFIG = {
-    "name": "tiny-dstree", "source": "test", "generator": "randwalk",
-    "n": 1200, "m": 16, "data_seed": 3, "precision": "float32",
-    "engine_strategy": "scan",
-    "leafi": {"backbone": "dstree", "leaf_capacity": 128, "n_global": 40,
-              "n_local": 10, "t_filter_over_t_series": 20.0,
-              "train_epochs": 1},
-    "limits": {"dist_err": 0.001, "recall_sigmas": 3, "calib_queries": 12},
-    "reduced": ["n"],
-}
-OPEN = {"loop": "open", "rate_qps": 100.0, "arrival_seed": 7, "k": 1,
-        "targets": [0.9, 0.95, 0.99], "noise": 0.1, "max_batch": 4,
-        "max_wait_s": 0.002, "in_flight": 1, "warmup_s": 0.5}
-CLOSED = {"loop": "closed", "outstanding": 8, "k": 3, "targets": None,
-          "noise": 0.1, "max_batch": 4, "warmup_s": 0.5}
+# What a twin changes.  A configuration keeps its generator, engine
+# strategy, backbone and every other key it sets; a traffic mix keeps its
+# loop, k, targets, seeds and in-flight depth.
+SIZES = {"n": 1200, "m": 16}
+LEAFI = {"leaf_capacity": 128, "n_global": 40, "n_local": 10,
+         "train_epochs": 1}
+LIMITS = {"calib_queries": 12}
+TRAFFIC = {"rate_qps": 100.0, "outstanding": 8, "max_batch": 4,
+           "warmup_s": 0.5}
 
 
-def make_root(path) -> str:
-    """BENCHMARK.json with the tiny cells (every metric of the real one,
-    its cells renamed), the tiny files, and the real metric readers."""
-    path = str(path)
-    os.makedirs(os.path.join(path, "bench", "configs"))
-    os.makedirs(os.path.join(path, "bench", "traffic"))
-    os.symlink(os.path.join(BENCH, "metrics"),
+def twin(name: str) -> str:
+    return f"tiny-{name}"
+
+
+def twin_config(config: dict) -> dict:
+    out = copy.deepcopy(config)
+    out.update(SIZES, name=twin(config["name"]))
+    out["leafi"].update(LEAFI)
+    out["limits"].update(LIMITS)
+    return out
+
+
+def twin_traffic(traffic: dict) -> dict:
+    return {key: TRAFFIC.get(key, v) for key, v in traffic.items()}
+
+
+def load(path: str = REAL) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def load_traffic(name: str, bench_json: str = REAL) -> dict:
+    """The traffic file ``name`` of the tree ``bench_json`` lies in."""
+    return load(os.path.join(os.path.dirname(os.path.abspath(bench_json)),
+                             "bench", "traffic", f"{name}.json"))
+
+
+def cells(bench_json: str = REAL) -> list:
+    """The twins' names of the cells of ``bench_json``."""
+    return [twin(w["name"]) for w in load(bench_json)["workloads"]]
+
+
+def make_root(path, bench_json: str = REAL) -> str:
+    """A benchmark tree at ``path`` mirroring ``bench_json`` and the
+    configuration, traffic and metric files of the tree it lies in."""
+    path, src = str(path), os.path.dirname(os.path.abspath(bench_json))
+    bench = load(bench_json)
+    for sub in ("configs", "traffic"):
+        os.makedirs(os.path.join(path, "bench", sub))
+    os.symlink(os.path.join(src, "bench", "metrics"),
                os.path.join(path, "bench", "metrics"))
-    files = {"configs/tiny-dstree.json": CONFIG,
-             "traffic/tiny-open.json": OPEN,
-             "traffic/tiny-closed.json": CLOSED}
-    for name, body in files.items():
-        with open(os.path.join(path, "bench", name), "w") as f:
+
+    def write(rel, body):
+        with open(os.path.join(path, rel), "w") as f:
             json.dump(body, f)
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    rename = {"rw256-approx-open": "tiny-open",
-              "deep96-exact-bulk": "tiny-closed"}
-    bench["configs"] = [{"name": "tiny-dstree", "source": "test",
-                         "file": "bench/configs/tiny-dstree.json",
-                         "reduced": ["n"], "why": "test"}]
-    bench["workloads"] = [
-        {"name": w, "config": "tiny-dstree", "traffic": w, "chips": 1,
-         "why": "test"} for w in ("tiny-open", "tiny-closed")]
+
+    for c in bench["configs"]:
+        with open(os.path.join(src, c["file"])) as f:
+            body = twin_config(json.load(f))
+        c.update(name=body["name"], file=f"bench/configs/{body['name']}.json")
+        write(c["file"], body)
+    for name in sorted({w["traffic"] for w in bench["workloads"]}):
+        write(f"bench/traffic/{twin(name)}.json",
+              twin_traffic(load_traffic(name, bench_json)))
+    for w in bench["workloads"]:
+        w.update(name=twin(w["name"]), config=twin(w["config"]),
+                 traffic=twin(w["traffic"]))
     for mt in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in mt:
-            mt["workloads"] = [rename[w] for w in mt["workloads"]]
-    with open(os.path.join(path, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
+            mt["workloads"] = [twin(w) for w in mt["workloads"]]
+    write("BENCHMARK.json", bench)
     return path
 
 
-def session(seed: int = 5):
-    """A served tiny index and its collection."""
+def first_config(bench_json: str = REAL) -> dict:
+    """The twin of the first configuration of ``bench_json``."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(bench_json)),
+                           load(bench_json)["configs"][0]["file"])) as f:
+        return twin_config(json.load(f))
+
+
+def first_traffic(loop: str, bench_json: str = REAL) -> dict:
+    """The twin of the first traffic mix of ``bench_json`` whose loop is
+    ``loop``."""
+    mixes = (load_traffic(w["traffic"], bench_json)
+             for w in load(bench_json)["workloads"])
+    return twin_traffic(next(t for t in mixes if t["loop"] == loop))
+
+
+def session(config: dict = None, seed: int = 5):
+    """A served tiny index of ``config`` (the first configuration's twin)
+    and its collection."""
     from bench import cell, gen
     from repro.core import build
     from repro.serving import ServingSession
-    collection = gen.make_collection(CONFIG, seed)
-    lfi = build.build_leafi(collection, cell.leafi_config(CONFIG, seed))
-    return ServingSession(lfi), collection
+    config = config or first_config()
+    collection = gen.make_collection(config, seed)
+    lfi = build.build_leafi(collection, cell.leafi_config(config, seed))
+    return ServingSession(lfi, strategy=config["engine_strategy"]), \
+        collection

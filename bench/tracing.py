@@ -93,6 +93,19 @@ def merge(intervals: Iterable[Tuple[float, float]], lo: float,
     return out
 
 
+def recorded_until(events: Sequence[Event], hi: float) -> float:
+    """Where the trace's device record of a window ending at ``hi`` ends:
+    ``hi``, or the end of the last device event where that comes first.
+
+    The profiler stops recording device events once its buffer is full (a
+    ``Trace Buffers Dropped`` event on the plane's ``XLA TraceMe`` line);
+    past that point the device is not idle, only unrecorded.
+    """
+    if not events:
+        return hi
+    return min(hi, max(e for _, _, e in events))
+
+
 def busy_ns(events: Sequence[Event], lo: float, hi: float) -> float:
     """Nanoseconds of [lo, hi] in which at least one event runs."""
     return sum(e - s for s, e in merge(((s, e) for _, s, e in events),
