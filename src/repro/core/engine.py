@@ -14,8 +14,10 @@ Two strategies over identical semantics:
 * ``strategy="scan"`` — every leaf's distances are computed, in one jitted
   fixed-shape program of two phases: a leaf-major pass reads each leaf's
   slab once for the whole batch (a contiguous ``dynamic_slice`` at the
-  leaf's start; the series is leaf-sorted) and keeps its k smallest
-  distances per query, then :func:`replay_cascade` replays the exact
+  leaf's start; the series is leaf-sorted), size class by size class
+  (``flat_index.leaf_classes``: a power-of-two slab no longer than the
+  class's leaves need), and keeps its k smallest distances per query,
+  then :func:`replay_cascade` replays the exact
   cascade over those summaries in each query's lower-bound order: the
   compact strategy's phase 3 with every leaf a survivor and no host
   round-trip.  Wall-clock is O(all leaves) regardless of how well the
@@ -54,9 +56,10 @@ Two strategies over identical semantics:
        where ‖q‖²+‖s‖²−2qs cancels), the same trade the ``l2_scan`` kernel
        itself makes.
 
-Cost model: scan is Q·L·R·m multiply-adds (R = max leaf size) and reads
-L·R·m series values per batch (each leaf once; a per-query walk reads
-Q·L·R·m), plus Q·L·k merge work in the replay; compact is Q·R·m (probe) +
+Cost model: scan is Q·S·m multiply-adds and reads S·m series values per
+batch, S = Σ leaves × slab rows over the size classes (L·R with one class
+of R = max leaf size; each leaf once, where a per-query walk reads
+Q·S·m), plus Q·L·k merge work in the replay; compact is Q·R·m (probe) +
 Σ_q C_q·R·m (candidates) + Q·L·k merge work, with C_q the survivor count —
 i.e. the heavy term scales with (1 − pruning ratio).
 Measured with the earlier per-query scan (benchmarks/engine_bench.py, CPU,
@@ -149,46 +152,67 @@ def _next_pow2(n: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _leafwise_topk(series, leaf_start, leaf_size, queries, kk, max_leaf):
-    """Every leaf's kk smallest distances to every query, leaf-major.
+def _class_topk(series, leaf_start, leaf_size, queries, kk, first, count,
+                rows):
+    """The kk smallest distances of one size class's leaves (ids ``first``
+    to ``first + count``) to every query.
 
-    ``_LEAVES_PER_STEP`` leaves per loop step; each leaf's slab is one
-    contiguous ``dynamic_slice`` at a scalar start (the series is
+    ``_LEAVES_PER_STEP`` leaves per loop step; each leaf's slab is ``rows``
+    rows, one contiguous ``dynamic_slice`` at a scalar start (the series is
     leaf-sorted), read once for the whole batch and scored in the direct
     form of the sequential cascade.  A slab gathered per query instead
-    lowers on the TPU to one serial copy per query.  Returns (vals (Q, L,
-    kk), ids (Q, L, kk)): +inf / −1 past a leaf's size, ties toward the
-    lower row (``l2_ops.leaf_topk``'s order).
+    lowers on the TPU to one serial copy per query.  Returns (vals (Q,
+    count, kk), ids (Q, count, kk)): +inf / −1 past a leaf's size, ties
+    toward the lower row (``l2_ops.leaf_topk``'s order).
     """
-    row_ids = jnp.arange(max_leaf)
+    kc = min(kk, rows)
+    row_ids = jnp.arange(rows)
 
     def step(_, leaf):
         start = leaf_start[leaf]
-        slab = jax.lax.dynamic_slice_in_dim(series, start, max_leaf, 0)
+        slab = jax.lax.dynamic_slice_in_dim(series, start, rows, 0)
         diff = slab[None] - queries[:, None, :]
         d = jnp.sqrt((diff * diff).sum(-1))                   # (Q, R)
         d = jnp.where(row_ids < leaf_size[leaf], d, _INF)
-        neg, arg = jax.lax.top_k(-d, kk)
+        neg, arg = jax.lax.top_k(-d, kc)
         ids = jnp.where(jnp.isfinite(neg), start + arg, -1)
         return None, (-neg, ids.astype(jnp.int32))
 
     _, (vals, ids) = jax.lax.scan(step, None,
-                                  jnp.arange(leaf_start.shape[0]),
+                                  jnp.arange(first, first + count),
                                   unroll=_LEAVES_PER_STEP)
-    return vals.transpose(1, 0, 2), ids.transpose(1, 0, 2)
+    pad = ((0, 0), (0, 0), (0, kk - kc))
+    return (jnp.pad(vals.transpose(1, 0, 2), pad, constant_values=_INF),
+            jnp.pad(ids.transpose(1, 0, 2), pad, constant_values=-1))
+
+
+def _leafwise_topk(series, leaf_start, leaf_size, queries, kk, leaf_classes):
+    """Every leaf's kk smallest distances to every query, leaf-major and
+    size class by size class (``flat_index.leaf_classes``: contiguous runs
+    of leaf ids, each read at one static slab length), so the pass reads
+    the class layout's rows rather than ``max_leaf`` rows a leaf.  Returns
+    (vals (Q, L, kk), ids (Q, L, kk)) in leaf order.
+    """
+    out = [_class_topk(series, leaf_start, leaf_size, queries, kk, *c)
+           for c in leaf_classes]
+    return (jnp.concatenate([v for v, _ in out], 1),
+            jnp.concatenate([i for _, i in out], 1))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("k", "max_leaf", "trace", "audit"))
+                   static_argnames=("k", "max_leaf", "leaf_classes", "trace",
+                                    "audit"))
 def _scan_cascade(series, leaf_start, leaf_size, queries, d_lb, d_F,
-                  bsf_ub, k, max_leaf, trace=False, audit=False):
+                  bsf_ub, k, max_leaf, leaf_classes=None, trace=False,
+                  audit=False):
     order = jnp.argsort(d_lb, axis=1)
     if not (trace or audit):
         # the compact strategy's two phases with every leaf a survivor:
         # each leaf's k smallest for the whole batch, then the exact
         # cascade replayed over them in each query's lb order.
+        classes = leaf_classes or ((0, leaf_start.shape[0], max_leaf),)
         leaf_d, leaf_i = _leafwise_topk(series, leaf_start, leaf_size,
-                                        queries, min(k, max_leaf), max_leaf)
+                                        queries, min(k, max_leaf), classes)
         return _replay_cascade(leaf_d, leaf_i, d_lb, d_F, order, k=k,
                                bsf_ub=bsf_ub)
 
@@ -676,6 +700,7 @@ def run_cascade(
     strategy: str = "auto",
     dist_impl: Optional[str] = None,
     bsf_ub: Optional[jnp.ndarray] = None,
+    leaf_classes: Optional[tuple] = None,
     trace: bool = False,
     audit: bool = False,
 ) -> EngineResult:
@@ -708,6 +733,9 @@ def run_cascade(
     filtered mode the conformal recall contract is preserved because a leaf
     with lb > ub holds no true top-k member.  +inf entries are the no-op
     seed.
+    leaf_classes: the index's size classes (``FlatIndex.leaf_classes``),
+    which the untraced "scan" leaf pass reads class by class; None reads a
+    ``max_leaf``-row slab for every leaf.
     trace: static flag; True additionally returns a per-query
     :class:`~repro.obs.trace.CascadeTrace` on ``EngineResult.trace``
     (which bound pruned which leaf, survivors, exact distance rows paid —
@@ -756,7 +784,8 @@ def run_cascade(
         else:
             td, ti, n_s, n_plb, n_pf = sanitize.call(
                 _scan_cascade, series, leaf_start, leaf_size, queries,
-                d_lb, d_F, ub, k=k, max_leaf=max_leaf)
+                d_lb, d_F, ub, k=k, max_leaf=max_leaf,
+                leaf_classes=leaf_classes)
         n_c = jnp.full(queries.shape[0], leaf_start.shape[0], jnp.int32)
     elif strategy == "compact":
         out = _compact_cascade(

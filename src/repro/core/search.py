@@ -221,8 +221,10 @@ def search_batched_async(
     ``search.bounds`` (the queries' upload and their lower bounds),
     ``search.offsets`` (the conformal offsets and their upload),
     ``search.filters`` (the filter sweep and its scatter onto leaves) and
-    ``search.cascade`` (the engine's enqueue).  An exact search has no
-    offsets or filters span.
+    ``search.cascade`` (the engine's enqueue; under the untraced ``scan``
+    its ``rows_read`` and ``rows_held`` args give the series rows the leaf
+    pass reads per batch and the rows the index holds).  An exact search
+    has no offsets or filters span.
     """
     shape = np.shape(queries)
     Q = int(shape[0]) if len(shape) > 1 else 1
@@ -255,11 +257,15 @@ def search_batched_async(
     else:
         d_F = jnp.full(d_lb.shape, -_INF)
 
-    with span("search.cascade", cat="search", q=Q, k=k, strategy=strategy):
+    rows = ({"rows_read": index.slab_rows, "rows_held": index.n_series}
+            if strategy == "scan" and not (trace or audit) else {})
+    with span("search.cascade", cat="search", q=Q, k=k, strategy=strategy,
+              **rows):
         res = engine.run_cascade(
             index.series, index.leaf_start, index.leaf_size, queries, d_lb,
             d_F, k=k, max_leaf=index.max_leaf_size, strategy=strategy,
-            dist_impl=dist_impl, bsf_ub=bsf_ub, trace=trace, audit=audit)
+            dist_impl=dist_impl, bsf_ub=bsf_ub,
+            leaf_classes=index.leaf_classes, trace=trace, audit=audit)
     return PendingSearch(raw=res, order=np.asarray(index.order),
                          n_series=index.n_series, n_leaves=index.n_leaves)
 

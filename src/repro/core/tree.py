@@ -24,7 +24,7 @@ from typing import List, Optional
 import numpy as np
 
 from . import summaries
-from .flat_index import FlatIndex
+from .flat_index import FlatIndex, leaf_classes
 
 
 @dataclasses.dataclass
@@ -230,4 +230,5 @@ def _flatten(series: np.ndarray, leaves: List[_Node], kind: str,
         n_series=n,
         length=m,
         payload={k: np.asarray(v) for k, v in payload.items()},
+        leaf_classes=leaf_classes(sizes, max_leaf),
     ).on_device()

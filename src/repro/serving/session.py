@@ -38,7 +38,7 @@ import numpy as np
 
 from .. import checkpoint
 from ..core import build, conformal, search
-from ..core.flat_index import FlatIndex
+from ..core.flat_index import FlatIndex, leaf_classes
 from ..obs import span
 from . import batcher as batcher_mod
 from .batcher import MicroBatch, MicroBatcher, Request, _pow2_floor
@@ -124,12 +124,14 @@ def load_index(path: str) -> build.LeaFiIndex:
         return {k[len(pre) + 1:][2:-2]: v
                 for k, v in flat.items() if k.startswith(pre + "/")}
 
+    max_leaf = int(meta["max_leaf_size"])
     index = FlatIndex(
         kind=meta["kind"], series=group("series"), order=group("order"),
         leaf_start=group("leaf_start"), leaf_size=group("leaf_size"),
-        max_leaf_size=int(meta["max_leaf_size"]),
+        max_leaf_size=max_leaf,
         n_series=int(meta["n_series"]), length=int(meta["length"]),
-        payload=group("payload")).on_device()
+        payload=group("payload"),
+        leaf_classes=leaf_classes(group("leaf_size"), max_leaf)).on_device()
     params = {k: jax.device_put(v)
               for k, v in group("filter_params").items()} or None
     tn = group("tuner")

@@ -13,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.core import bounds, build, engine, filter_training, search, tree
+from repro.core import (bounds, build, engine, filter_training, flat_index,
+                        search, tree)
 
 
 @pytest.fixture(scope="module", params=["dstree", "isax"])
@@ -98,52 +99,121 @@ def test_k_larger_than_leaf_capacity(index_small, queries_small):
     _assert_bitwise(a, b)
 
 
-def _scan(index, queries, d_lb, d_F, k, bsf_ub, trace):
+@pytest.fixture(scope="module", params=["dstree", "isax", "isax-w16"])
+def index_scan(request, randwalk_small):
+    """The two backbones of ``index_small``, and an iSAX trie of word
+    length 16 whose leaves hold as little as one row: the scan's leaf pass
+    reads them in one or two size classes, and in several."""
+    rows = randwalk_small[:2000]
+    if request.param == "dstree":
+        return tree.build_dstree(rows, leaf_capacity=64)
+    if request.param == "isax":
+        return tree.build_isax(rows, leaf_capacity=64)
+    return tree.build_isax(rows, leaf_capacity=64, word_len=16)
+
+
+def _scan(index, queries, d_lb, d_F, k, bsf_ub, trace, leaf_classes=None):
     return engine.run_cascade(
         jnp.asarray(index.series), jnp.asarray(index.leaf_start),
         jnp.asarray(index.leaf_size), queries, d_lb, d_F,
         k=k, max_leaf=index.max_leaf_size, strategy="scan", bsf_ub=bsf_ub,
-        trace=trace)
+        leaf_classes=leaf_classes, trace=trace)
 
 
 @pytest.mark.parametrize("k", [1, 10, "over_leaf"])
 def test_leafwise_scan_matches_sequential_scan_bitwise(
-        index_small, queries_small, k):
+        index_scan, queries_small, k):
     """The untraced scan (every leaf's k smallest read leaf-major once for
-    the batch, then the replay) == the sequential per-query cascade that
+    the batch, size class by size class, then the replay) == the same pass
+    over full ``max_leaf`` slabs == the sequential per-query cascade that
     trace=True keeps == compact with the direct distance form, bitwise,
     with filters pruning, a finite warm-start bound and a batch of 11."""
     q = jnp.asarray(queries_small[:11])
     if k == "over_leaf":
-        k = index_small.max_leaf_size + 17
-    d_lb = bounds.lower_bounds(index_small, q)
+        k = index_scan.max_leaf_size + 17
+    d_lb = bounds.lower_bounds(index_scan, q)
     d_F = _synthetic_predictions(d_lb, seed=5)
-    want_d, _ = _brute_force(index_small, np.asarray(q), k)
+    want_d, _ = _brute_force(index_scan, np.asarray(q), k)
     ub = jnp.asarray(want_d[:, -1] * np.float32(1.05))
-    a = _scan(index_small, q, d_lb, d_F, k, ub, trace=False)
-    b = _scan(index_small, q, d_lb, d_F, k, ub, trace=True)
+    a = _scan(index_scan, q, d_lb, d_F, k, ub, trace=False,
+              leaf_classes=index_scan.leaf_classes)
+    full = _scan(index_scan, q, d_lb, d_F, k, ub, trace=False)
+    b = _scan(index_scan, q, d_lb, d_F, k, ub, trace=True)
     c = engine.run_cascade(
-        jnp.asarray(index_small.series), jnp.asarray(index_small.leaf_start),
-        jnp.asarray(index_small.leaf_size), q, d_lb, d_F,
-        k=k, max_leaf=index_small.max_leaf_size, strategy="compact",
+        jnp.asarray(index_scan.series), jnp.asarray(index_scan.leaf_start),
+        jnp.asarray(index_scan.leaf_size), q, d_lb, d_F,
+        k=k, max_leaf=index_scan.max_leaf_size, strategy="compact",
         dist_impl="direct", bsf_ub=ub)
     assert np.asarray(b.n_pruned_filter).sum() > 0   # the cascade is active
+    _assert_bitwise(a, full)
     _assert_bitwise(a, b)
     _assert_bitwise(a, c)
 
 
-def test_untraced_scan_gathers_no_series_slab(index_small, queries_small):
+def test_leaf_classes_are_contiguous_runs_of_the_sorted_sizes(index_scan):
+    """Each class is a run of leaf ids, in order, read at one power-of-two
+    slab (at least 8 rows, at most max_leaf) that holds its leaves and no
+    power of two below it would; the 1-row-leaf trie reads far fewer rows
+    than full slabs would."""
+    sizes = np.asarray(index_scan.leaf_size)
+    ml = index_scan.max_leaf_size
+    classes = index_scan.leaf_classes
+    assert (np.diff(sizes) <= 0).all()               # the builders' order
+    assert [c[0] for c in classes] == list(
+        np.cumsum([0] + [c[1] for c in classes[:-1]]))
+    assert sum(c[1] for c in classes) == len(sizes)
+    assert [c[2] for c in classes] == sorted({c[2] for c in classes},
+                                             reverse=True)
+    for first, count, rows in classes:
+        got = sizes[first:first + count]
+        assert got.max() <= rows <= ml
+        assert rows == ml or (rows >= flat_index.MIN_SLAB_ROWS
+                              and rows & (rows - 1) == 0)
+        assert rows == flat_index.MIN_SLAB_ROWS or got.min() > rows // 2
+    assert index_scan.slab_rows == sum(c * r for _, c, r in classes)
+    assert index_scan.slab_rows <= len(sizes) * ml
+    if index_scan.kind == "isax" and sizes.min() == 1:
+        assert index_scan.slab_rows < len(sizes) * ml / 2
+
+
+@pytest.mark.parametrize("sizes, max_leaf, want", [
+    ([256, 256, 200, 129, 128, 65, 9, 8, 3, 1], 256,
+     ((0, 4, 256), (4, 2, 128), (6, 1, 16), (7, 3, 8))),
+    ([47, 40, 20, 1], 47, ((0, 2, 47), (2, 1, 32), (3, 1, 8))),
+    ([4, 2, 1], 4, ((0, 3, 4),)),
+    ([8, 200, 3], 200, ((0, 3, 200),)),              # unsorted: one class
+])
+def test_leaf_classes_by_hand(sizes, max_leaf, want):
+    assert flat_index.leaf_classes(np.asarray(sizes), max_leaf) == want
+
+
+def test_scan_lowers_once_per_batch_shape(index_scan, queries_small):
+    """The class layout is static: batches of one (Q, k) reuse one program
+    whatever their queries, targets and bounds."""
+    run = engine._scan_cascade
+    before = run._cache_size()
+    for lo, k in ((0, 3), (7, 3), (14, 3), (0, 1), (7, 1)):
+        q = jnp.asarray(queries_small[lo:lo + 7])
+        d_lb = bounds.lower_bounds(index_scan, q)
+        _scan(index_scan, q, d_lb, _synthetic_predictions(d_lb, seed=lo), k,
+              None, trace=False, leaf_classes=index_scan.leaf_classes)
+    assert run._cache_size() - before == 2
+
+
+def test_untraced_scan_gathers_no_series_slab(index_scan, queries_small):
     """The mechanism: the untraced scan reads the series only through
-    dynamic slices at a scalar (per-leaf) start.  A gather whose operand is
-    the series is what the chip runs as one serial copy per query."""
+    dynamic slices at a scalar (per-leaf) start, in every size class.  A
+    gather whose operand is the series is what the chip runs as one serial
+    copy per query."""
     q = jnp.asarray(queries_small[:5])
-    series = jnp.asarray(index_small.series)
-    d_lb = bounds.lower_bounds(index_small, q)
+    series = jnp.asarray(index_scan.series)
+    d_lb = bounds.lower_bounds(index_scan, q)
     txt = engine._scan_cascade.lower(
-        series, jnp.asarray(index_small.leaf_start),
-        jnp.asarray(index_small.leaf_size), q, d_lb,
+        series, jnp.asarray(index_scan.leaf_start),
+        jnp.asarray(index_scan.leaf_size), q, d_lb,
         jnp.full(d_lb.shape, -jnp.inf), jnp.full((5,), jnp.inf),
-        k=3, max_leaf=index_small.max_leaf_size).as_text()
+        k=3, max_leaf=index_scan.max_leaf_size,
+        leaf_classes=index_scan.leaf_classes).as_text()
     series_t = "tensor<{}x{}xf32>".format(*series.shape)
     gathers = [ln for ln in txt.splitlines() if "stablehlo.gather" in ln]
     assert gathers                       # the small per-query gathers stay

@@ -83,10 +83,8 @@ def test_target_array_length_mismatch_raises(lfi, mixed_queries):
                               **_search_kw(lfi))
 
 
-def test_per_group_recall_meets_targets_on_calibration_queries(lfi):
-    """Mixed targets on the build's own calibration split: each group's
-    achieved recall must meet its requested target, up to the one-query
-    quantization of a small group (1/n)."""
+def _calibration_split(lfi):
+    """The global queries build_leafi calibrated on, and mixed targets."""
     cfg = lfi.config
     key = jax.random.PRNGKey(cfg.seed)
     kdata, _ = jax.random.split(key)
@@ -95,11 +93,14 @@ def test_per_group_recall_meets_targets_on_calibration_queries(lfi):
         np.asarray(lfi.index.series[:lfi.index.n_series]),
         cfg.n_global, kg, 0.1, 0.4)
     n_cal = max(int(cfg.n_global * cfg.calib_fraction), 8)
-    calib = gq[-n_cal:]                   # the split build_leafi calibrated on
     rng = np.random.default_rng(3)
     targets = np.asarray([0.7, 0.85, 0.95])[rng.integers(0, 3, n_cal)]
-    exact = lfi.search_exact(calib)
-    res = lfi.search(calib, quality_target=targets)
+    return gq[-n_cal:], targets
+
+
+def _assert_recall_meets_targets(res, exact, targets):
+    """Each target group's recall@1 meets its target, up to the one-query
+    quantization of a small group (1/n)."""
     hit = np.asarray(conformal.recall_at_1(res.dists[:, 0],
                                            exact.dists[:, 0])) > 0
     for t in np.unique(targets):
@@ -107,6 +108,54 @@ def test_per_group_recall_meets_targets_on_calibration_queries(lfi):
         recall = hit[sel].mean()
         assert recall >= t - 1.0 / sel.sum() - 1e-9, \
             f"target {t}: recall {recall:.3f} over {sel.sum()} queries"
+
+
+def test_per_group_recall_meets_targets_on_calibration_queries(lfi):
+    """Mixed targets on the build's own calibration split: each group's
+    achieved recall must meet its requested target."""
+    calib, targets = _calibration_split(lfi)
+    _assert_recall_meets_targets(lfi.search(calib, quality_target=targets),
+                                 lfi.search_exact(calib), targets)
+
+
+@pytest.fixture(scope="module")
+def lfi_isax16(randwalk_small):
+    """An iSAX trie of word length 16 (iSAX2+ / MESSI's), whose leaves run
+    from one row to the capacity."""
+    cfg = build.LeaFiConfig(backbone="isax", word_len=16, leaf_capacity=64,
+                            n_global=200, n_local=50,
+                            t_filter_over_t_series=10.0,
+                            train=filter_training.TrainConfig(epochs=30))
+    return build.build_leafi(randwalk_small[:2500], cfg)
+
+
+def test_isax16_scan_session_exact_and_recall(lfi_isax16, mixed_queries):
+    """The scan engine reads the trie's leaves by size class: its exact
+    answers are brute force's, ids and distances, its approximate ones
+    meet each target on the calibration split, and its cascade span
+    counts the rows the leaf pass reads."""
+    from repro.obs import spans
+    idx = lfi_isax16.index
+    assert np.asarray(idx.leaf_size).min() == 1 and len(idx.leaf_classes) > 2
+    session = ServingSession(lfi_isax16, strategy="scan")
+    q, _ = mixed_queries
+    with spans.recording() as rec:
+        got = session.search_exact(q, k=5)
+    S = np.asarray(idx.series[:idx.n_series])
+    d = np.sqrt(((q[:, None, :] - S[None]) ** 2).sum(-1))
+    rows = np.argsort(d, axis=1, kind="stable")[:, :5]
+    np.testing.assert_allclose(got.dists, np.take_along_axis(d, rows, 1),
+                               rtol=1e-5)
+    np.testing.assert_array_equal(got.ids, np.asarray(idx.order)[rows])
+    cascade = [sp for sp in rec.spans() if sp.name == "search.cascade"]
+    assert [(sp.args["rows_read"], sp.args["rows_held"])
+            for sp in cascade] == [(idx.slab_rows, idx.n_series)]
+    assert idx.slab_rows < idx.n_leaves * idx.max_leaf_size / 2
+
+    calib, targets = _calibration_split(lfi_isax16)
+    _assert_recall_meets_targets(
+        session.search(calib, quality_targets=targets),
+        session.search_exact(calib), targets)
 
 
 # ---------------------------------------------------------------------------
@@ -194,6 +243,26 @@ def test_index_checkpoint_roundtrip_search_parity(lfi, mixed_queries,
     a = lfi.search(q, k=3, quality_target=targets)
     b = lfi2.search(q, k=3, quality_target=targets)
     # identical arrays through identical programs: exact equality
+    np.testing.assert_array_equal(a.dists, b.dists)
+    np.testing.assert_array_equal(a.ids, b.ids)
+    np.testing.assert_array_equal(a.searched, b.searched)
+
+
+def test_index_checkpoint_roundtrip_keeps_leaf_classes(lfi_isax16,
+                                                       mixed_queries,
+                                                       tmp_path):
+    """The loaded index reads the same size classes, so the scan engine
+    answers as it did before the save, bitwise."""
+    q, targets = mixed_queries
+    path = str(tmp_path / "isax16_idx")
+    save_index(path, lfi_isax16)
+    lfi2 = load_index(path)
+    assert lfi2.index.leaf_classes == lfi_isax16.index.leaf_classes
+    assert lfi2.index.slab_rows == lfi_isax16.index.slab_rows
+    a = ServingSession(lfi_isax16, strategy="scan").search(
+        q, targets, k=3, record=False)
+    b = ServingSession(lfi2, strategy="scan").search(
+        q, targets, k=3, record=False)
     np.testing.assert_array_equal(a.dists, b.dists)
     np.testing.assert_array_equal(a.ids, b.ids)
     np.testing.assert_array_equal(a.searched, b.searched)
